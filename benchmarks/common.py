@@ -43,7 +43,8 @@ def on_interpret(backend_name: str) -> Optional[bool]:
     applicable) for the pure-jnp ones."""
     if not backend_name.startswith("pallas"):
         return None
-    return jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    return interpret_mode()
 
 
 def emit(name: str, us_per_call: float, derived: str = "", *,

@@ -20,7 +20,10 @@ def main() -> None:
     tables = args or TABLES
     import json
 
+    from repro.launch.cache import enable_compile_cache
+
     from .common import ROWS, ROWS_META, emit
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for t in tables:
         mod = importlib.import_module(f"benchmarks.{t}")

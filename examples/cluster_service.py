@@ -21,7 +21,10 @@ from repro.data.loader import ShardedLoader, normalize
 from repro.data.synth import make_kdd_like
 from repro.ft.checkpoint import CheckpointManager
 from repro.ft.elastic import StragglerMonitor
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
+
+enable_compile_cache()
 
 C = 23                    # KDD99-like: 23 classes, 41 features
 CHUNK, BATCH_ROWS, N_CHUNKS = 40_000, 120_000, 6

@@ -17,12 +17,15 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.core.bigfcm import BigFCMConfig
 from repro.integration import fcm_router_init
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import build
 from repro.models import transformer as tf
 from repro.models.moe import router_load
 from repro.models.params import tree_init
 from repro.sharding.rules import mesh_context
+
+enable_compile_cache()
 
 cfg = dataclasses.replace(reduced(get_config("olmoe-1b-7b")),
                           n_experts=16, top_k=4)

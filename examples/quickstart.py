@@ -17,7 +17,10 @@ from repro.core.bigfcm import BigFCMConfig, bigfcm_fit
 from repro.core.fcm import fcm
 from repro.core.metrics import assign, match_centers, silhouette_width
 from repro.data.synth import make_blobs
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
+
+enable_compile_cache()
 
 C, D, N = 6, 18, 200_000
 

@@ -11,11 +11,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.models.params import tree_init
 from repro.serve import greedy_generate, make_prefill, make_serve_step
 from repro.sharding.rules import mesh_context
+
+enable_compile_cache()
 
 cfg = reduced(get_config("stablelm-12b"))
 mesh = make_host_mesh()

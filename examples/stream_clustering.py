@@ -30,6 +30,9 @@ from repro.data import (make_blobs, make_moving_blobs, out_of_order_source,
 from repro.ft import CheckpointManager
 from repro.serve import assign_stream
 from repro.stream import StreamConfig, StreamingBigFCM
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 C, D, CHUNK, N_CHUNKS, DRIFT_AT = 5, 12, 4000, 12, 6
 

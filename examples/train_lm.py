@@ -14,6 +14,7 @@ import dataclasses
 import tempfile
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import train
 
@@ -35,6 +36,7 @@ def model_tiny():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--steps", type=int, default=None)

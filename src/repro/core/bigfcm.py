@@ -47,14 +47,14 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.data.cache import ChunkStore
-from repro.data.plane import PartitionPlan, batched, plan_partitions, \
-    shard_batches
+from repro.data.plane import PartitionPlan, batched, pad_rows, \
+    plan_partitions, shard_batches
 from repro.engine import (MergePlan, Summary, merge_summaries,
                           resolve_backend)
 
@@ -221,6 +221,24 @@ def _combine_reduce(x_local, w_local, v_init, *, cfg: BigFCMConfig,
     return centers, red.summary.masses, q, iters, red.n_iter
 
 
+def mesh_job(mesh: Mesh, cfg: BigFCMConfig, *, flag: bool, backend,
+             data_axes: Sequence[str] = ("data",)):
+    """The combiner+reducer as ONE `shard_map` program over ``mesh``:
+    ``(x, w, v_init)``, with x and w row-sharded over ``data_axes`` and
+    v_init replicated → ``(centers, masses, q, combiner iters, reducer
+    iters)``.  Rows must divide evenly over the data axes."""
+    data_axes = tuple(data_axes)
+    pod_axis = "pod" if "pod" in mesh.axis_names else None
+    return shard_map(
+        partial(_combine_reduce, cfg=cfg, flag=flag, backend=backend,
+                data_axes=data_axes, pod_axis=pod_axis),
+        mesh=mesh,
+        in_specs=(P(data_axes), P(data_axes), P(None, None)),
+        out_specs=(P(None, None), P(None), P(), P(None), P()),
+        check_vma=False,
+    )
+
+
 # ------------------------------------------------------------------ fit ---
 
 def bigfcm_fit(
@@ -289,17 +307,14 @@ def _fit_array(x, cfg: BigFCMConfig, *, mesh, data_axes, point_weights,
                             diag)
 
     data_axes = tuple(data_axes)
-    pod_axis = "pod" if "pod" in mesh.axis_names else None
-    x_spec = P(data_axes)
-    job = shard_map(
-        partial(_combine_reduce, cfg=cfg, flag=flag, backend=be,
-                data_axes=data_axes, pod_axis=pod_axis),
-        mesh=mesh,
-        in_specs=(x_spec, P(data_axes), P(None, None)),
-        out_specs=(P(None, None), P(None), P(), P(None), P()),
-        check_vma=False,
-    )
-    x_sharded = jax.device_put(x, NamedSharding(mesh, x_spec))
+    # Rows shard evenly over the data axes: pad to a multiple of their
+    # size with zero-weight phantom rows, a no-op in every accumulator.
+    n_pad = -n % int(np.prod([mesh.shape[a] for a in data_axes]))
+    if n_pad:
+        x = pad_rows(np.asarray(x), n + n_pad)
+        w = jnp.concatenate([w, jnp.zeros((n_pad,), jnp.float32)])
+    job = mesh_job(mesh, cfg, flag=flag, backend=be, data_axes=data_axes)
+    x_sharded = jax.device_put(x, NamedSharding(mesh, P(data_axes)))
     w_sharded = jax.device_put(w, NamedSharding(mesh, P(data_axes)))
     v_rep = jax.device_put(v_init, NamedSharding(mesh, P(None, None)))
     centers, cw, q, iters, r_it = jax.jit(job)(x_sharded, w_sharded, v_rep)

@@ -30,10 +30,11 @@ know their workload pass ``shape=(n_records, n_clusters, dim)`` so the
 race runs in the right bucket; without it a representative default
 bucket is used.  The old platform-name rule (TPU → ``pallas``, else →
 ``jnp``) survives as `default_backend_name()`, the fallback when
-calibration is disabled (``REPRO_AUTO_CALIBRATE=0``) or the perf layer
-fails.  The Pallas backends register themselves from
-`repro.kernels.ops` on first lookup, so this module has no hard kernel
-dependency.
+calibration is disabled (``REPRO_AUTO_CALIBRATE=0``) or, off the TPU,
+when the perf layer fails.  On a TPU no fallback hides a failed kernel:
+a broken kernels import, perf layer or kernel compile raises.  The
+Pallas backends register themselves from `repro.kernels.ops` on first
+lookup, so this module has no hard kernel dependency.
 
 The sweep math itself (pairwise distances, log-space membership terms)
 lives here — it is the engine's foundation; `repro.core.fcm` re-exports
@@ -50,17 +51,22 @@ import jax.numpy as jnp
 from repro import obs
 
 _D2_FLOOR = 1e-12  # distance floor: a record sitting exactly on a center
+_F32 = jax.lax.Precision.HIGHEST  # f32 matmuls stay f32 on a TPU too
 
 
 # ------------------------------------------------------------ sweep math ---
 
 def pairwise_sqdist(x: jax.Array, centers: jax.Array) -> jax.Array:
-    """‖x−v‖² via the MXU-friendly expansion x² + v² − 2·x·vᵀ."""
+    """‖x−v‖² via the MXU-friendly expansion x² + v² − 2·x·vᵀ.
+
+    The cross term runs at full f32 precision on every platform (a TPU's
+    default would round its inputs to bf16): the expansion cancels, and
+    near-equidistant records would change their nearest center."""
     x = x.astype(jnp.float32)
     centers = centers.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (N, 1)
     v2 = jnp.sum(centers * centers, axis=-1)             # (C,)
-    cross = x @ centers.T                                # (N, C) — matmul
+    cross = jnp.matmul(x, centers.T, precision=_F32)     # (N, C) — matmul
     return jnp.maximum(x2 + v2 - 2.0 * cross, _D2_FLOOR)
 
 
@@ -103,7 +109,8 @@ def fcm_accumulate(x, weights, centers, m):
     d2 = pairwise_sqdist(x, centers)
     wum = _um_from_d2(d2, m) * weights[:, None]     # w_k · u_ik^m
     w_i = jnp.sum(wum, axis=0)                      # (C,)
-    v_num = wum.T @ x.astype(jnp.float32)           # (C, d) — matmul
+    v_num = jnp.matmul(wum.T, x.astype(jnp.float32),
+                       precision=_F32)                # (C, d) — matmul
     q = jnp.sum(wum * d2)                           # objective, Eq. (2)
     return v_num, w_i, q
 
@@ -269,6 +276,13 @@ class Bf16Backend(SweepBackend):
 _REGISTRY: Dict[str, SweepBackend] = {}
 _KERNELS_PROBED = False
 
+
+def on_tpu() -> bool:
+    """Whether this process runs on a TPU, where every fallback that
+    would hide a failed kernel raises instead."""
+    return jax.default_backend() == "tpu"
+
+
 BackendLike = Union[None, str, SweepBackend]
 
 
@@ -281,24 +295,27 @@ def register_backend(backend: SweepBackend) -> SweepBackend:
 def _probe_kernel_backends() -> None:
     """Import `repro.kernels.ops` once so its backends self-register.
 
-    A broken kernels layer (pallas API skew raises beyond ImportError)
-    degrades to the jnp paths — but LOUDLY: exactly one warning per
-    process, routed through the obs event sink (`obs.warn_once`) with
-    the original import error kept in the event payload, so
-    "everything silently runs 50× slower on the reference backend"
-    can't happen without a signal."""
+    On a TPU a broken kernels layer raises the original import error:
+    the Pallas sweep is the product there, and a silent fall back to the
+    jnp reference would hide it.  Elsewhere the kernels only serve
+    parity testing, so a failed import degrades to the jnp paths — but
+    LOUDLY: exactly one warning per process, routed through the obs
+    event sink (`obs.warn_once`) with the original import error kept in
+    the event payload."""
     global _KERNELS_PROBED
     if _KERNELS_PROBED:
         return
-    _KERNELS_PROBED = True
     try:
         importlib.import_module("repro.kernels.ops")  # registers pallas
     except Exception as e:
+        if on_tpu():
+            raise
         obs.warn_once(
             "kernels_probe_failed",
             "repro.kernels.ops failed to import — Pallas sweep backends "
             f"are unavailable this process; falling back to jnp: {e!r}",
             stacklevel=3, error=repr(e))
+    _KERNELS_PROBED = True
 
 
 def available_backends() -> list:
@@ -321,27 +338,29 @@ def default_backend_name() -> str:
     ``jnp``.  Since PR 6 this is a FALLBACK, not the auto-selection:
     ``resolve_backend("auto")`` picks by measured race
     (`repro.perf.calibrate`) and only lands here when calibration is
-    disabled or the perf layer is broken.  The Pallas kernel's
-    revisited-output-block accumulation is a Mosaic (TPU) semantic, so
-    GPU hosts get the jnp reference too; on CPU the pallas backends
-    stay registered in interpret mode for parity testing.  A TPU host
-    whose kernels layer failed to import degrades to ``jnp`` (slow but
-    correct) rather than KeyError-ing."""
-    if jax.default_backend() == "tpu":
+    disabled.  The Pallas kernel's revisited-output-block accumulation
+    is a Mosaic (TPU) semantic, so GPU hosts get the jnp reference too;
+    on CPU the pallas backends stay registered in interpret mode for
+    parity testing.  On a TPU the kernels import raises if it fails
+    (`_probe_kernel_backends`), so ``pallas`` is always registered
+    there."""
+    if on_tpu():
         _probe_kernel_backends()
-        if "pallas" in _REGISTRY:
-            return "pallas"
+        return "pallas"
     return "jnp"
 
 
 def _calibrated_name(shape: Optional[Tuple[int, int, int]]) -> Optional[str]:
     """Measured winner via `repro.perf.calibrate`, or None to fall back
-    to the platform rule (calibration disabled / perf layer broken —
-    the latter warns once, same contract as the kernels probe)."""
+    to the platform rule (calibration disabled).  A broken perf layer
+    raises on a TPU; elsewhere it warns once and falls back, the same
+    contract as the kernels probe."""
     try:
         from repro.perf.calibrate import calibrated_backend_name
         name = calibrated_backend_name(shape)
     except Exception as e:
+        if on_tpu():
+            raise
         obs.warn_once(
             "perf_calibration_failed",
             "repro.perf calibration failed — backend auto-selection "

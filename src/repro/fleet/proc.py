@@ -15,6 +15,12 @@ This is also the honest statement of the simulated-vs-real boundary:
 `sim.fleet_fit` and `run_fleet` drive the IDENTICAL `FleetHost`
 protocol; only the transport (condvar vs files) and the failure
 injector (thread exception vs SIGKILL) differ.
+
+The spawned hosts run on the CPU, whatever the machine holds: each
+child pins JAX to the CPU before its first device use.  An accelerator
+belongs to one process at a time, so N children (and a parent that
+has touched JAX) could not share it.  Multi-chip BigFCM is the
+in-process `shard_map` fit (`core.bigfcm`) or `spmd.mesh_exchange`.
 """
 from __future__ import annotations
 
@@ -37,6 +43,9 @@ def host_main(host_id: int, n_hosts: int, store_dir: str, fleet_dir: str,
     `FleetConfig` — primitives only, so spawn never pickles live jax
     state across the process boundary."""
     # import inside the child: a spawned interpreter starts cold
+    import jax
+    jax.config.update("jax_platforms", "cpu")   # see the module docstring
+
     from repro import obs
     from repro.core.bigfcm import BigFCMConfig
     from repro.data.cache import ChunkStore

@@ -4,8 +4,7 @@ When the "hosts" are devices of one jax mesh (a real multi-host SPMD
 job, or a forced-multi-device simulation via
 ``--xla_force_host_platform_device_count``), the transport layer
 disappears entirely: the exchange is an ``all_gather`` of the per-host
-summary inside `shard_map` (through `repro.compat`, like every other
-shard_map in the repo) followed by the same pairwise merge — run
+summary inside `jax.shard_map` followed by the same pairwise merge — run
 replicated on every device, exactly as `FleetHost.exchange` runs it on
 every process.
 
@@ -22,9 +21,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.engine import MergePlan, Summary, merge_summaries
 
 

@@ -17,6 +17,10 @@ the combiner hot loop re-thought for the TPU memory hierarchy):
   * C and d are zero-padded to multiples of 128 (MXU lane width); phantom
     centers are masked out of the membership denominator, phantom rows
     carry weight 0.
+  * The record weights ride in the padded X block, in lane ``d`` (the
+    first padding lane), so there is no separate (N, 1) weight array:
+    in HBM such an array fills a 128-lane tile per row, as many bytes
+    as X itself.  V is zero in that lane, so it adds nothing to x·vᵀ.
   * The three outputs (center numerators C×d, center masses C, objective)
     map every grid step to the same output block and accumulate across
     steps (revisited-block accumulation).
@@ -35,11 +39,15 @@ from jax.experimental import pallas as pl
 
 _D2_FLOOR = 1e-12
 LANE = 128
+# Both MXU contractions run at full f32 precision: d² = x² + v² − 2·x·vᵀ
+# cancels, and the kernel must agree with the f32 reference sweep.
+_F32 = jax.lax.Precision.HIGHEST
 
 
-def _fcm_tile_kernel(x_ref, w_ref, v_ref, vnum_ref, wacc_ref, q_ref,
-                     *, m: float, n_centers: int):
-    """One grid step: accumulate a TILE_N slab of records."""
+def _fcm_tile_kernel(x_ref, v_ref, vnum_ref, wacc_ref, q_ref,
+                     *, m: float, n_centers: int, dim: int):
+    """One grid step: accumulate a TILE_N slab of records (features in
+    lanes ``[0, dim)``, the record weight in lane ``dim``)."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -48,15 +56,18 @@ def _fcm_tile_kernel(x_ref, w_ref, v_ref, vnum_ref, wacc_ref, q_ref,
         wacc_ref[...] = jnp.zeros_like(wacc_ref)
         q_ref[...] = jnp.zeros_like(q_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # (TN, dp)
-    w = w_ref[...].astype(jnp.float32)            # (TN, 1)
+    xw = x_ref[...].astype(jnp.float32)           # (TN, dp)
     v = v_ref[...].astype(jnp.float32)            # (Cp, dp)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, xw.shape[1]), 1)
+    w = jnp.sum(jnp.where(lane == dim, xw, 0.0), axis=-1,
+                keepdims=True)                    # (TN, 1)
+    x = jnp.where(lane < dim, xw, 0.0)            # (TN, dp)
 
     # ‖x−v‖² via the MXU: x² + v² − 2·x·vᵀ
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)               # (TN, 1)
     v2 = jnp.sum(v * v, axis=-1)[None, :]                     # (1, Cp)
     cross = jax.lax.dot_general(
-        x, v, (((1,), (1,)), ((), ())),
+        x, v, (((1,), (1,)), ((), ())), precision=_F32,
         preferred_element_type=jnp.float32)                   # (TN, Cp) MXU
     d2 = jnp.maximum(x2 + v2 - 2.0 * cross, _D2_FLOOR)
 
@@ -76,7 +87,7 @@ def _fcm_tile_kernel(x_ref, w_ref, v_ref, vnum_ref, wacc_ref, q_ref,
 
     # accumulate: V numerators (MXU), center masses, objective
     vnum_ref[...] += jax.lax.dot_general(
-        wum, x, (((0,), (0,)), ((), ())),
+        wum, x, (((0,), (0,)), ((), ())), precision=_F32,
         preferred_element_type=jnp.float32)                    # (Cp, dp)
     wacc_ref[...] += jnp.sum(wum, axis=0, keepdims=True)       # (1, Cp)
     q_ref[...] += jnp.sum(wum * d2, keepdims=True).reshape(1, 1)
@@ -111,26 +122,25 @@ def fcm_accumulate_pallas(x, w, centers, m: float = 2.0, *,
     """
     n, d = x.shape
     c = centers.shape[0]
-    dp = _pad_to(max(d, lane), lane)
+    dp = _pad_to(d + 1, lane)         # lane d carries the record weight
     cp = _pad_to(max(c, lane), lane)
     tn = min(tile_n, _pad_to(n, 8))
     np_ = _pad_to(n, tn)
 
-    xf = jnp.zeros((np_, dp), jnp.float32).at[:n, :d].set(
-        x.astype(jnp.float32))
-    wf = jnp.zeros((np_, 1), jnp.float32).at[:n, 0].set(
-        w.astype(jnp.float32))
+    # one fused concatenate+pad: no (N, 1) intermediate reaches HBM
+    xf = jnp.pad(jnp.concatenate([x.astype(jnp.float32),
+                                  w.astype(jnp.float32)[:, None]], axis=1),
+                 ((0, np_ - n), (0, dp - d - 1)))
     vf = jnp.zeros((cp, dp), jnp.float32).at[:c, :d].set(
         centers.astype(jnp.float32))
 
     grid = (np_ // tn,)
-    kernel = functools.partial(_fcm_tile_kernel, m=m, n_centers=c)
+    kernel = functools.partial(_fcm_tile_kernel, m=m, n_centers=c, dim=d)
     vnum, wacc, q = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tn, dp), lambda i: (i, 0)),   # X streamed
-            pl.BlockSpec((tn, 1), lambda i: (i, 0)),    # w streamed
+            pl.BlockSpec((tn, dp), lambda i: (i, 0)),   # X, w streamed
             pl.BlockSpec((cp, dp), lambda i: (0, 0)),   # V resident
         ],
         out_specs=[
@@ -144,7 +154,7 @@ def fcm_accumulate_pallas(x, w, centers, m: float = 2.0, *,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(xf, wf, vf)
+    )(xf, vf)
 
     return vnum[:c, :d], wacc[0, :c], q[0, 0]
 

@@ -16,13 +16,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.engine.backend import (SweepBackend, normalize_accumulators,
-                                  register_backend)
+                                  on_tpu, register_backend)
 
 from .fcm_update import (_D2_FLOOR, LANE, fcm_accumulate_pallas,
                          fcm_sweep_pallas)
 
 
-def _on_cpu() -> bool:
+def interpret_mode() -> bool:
+    """Whether the kernels run in Pallas interpret mode: on the CPU,
+    for parity testing.  Elsewhere Mosaic compiles them."""
     return jax.default_backend() == "cpu"
 
 
@@ -30,14 +32,17 @@ def _blocks_for(x, centers, tile_n, lane) -> dict:
     """Resolve the kernel's block sizes: explicit args win, otherwise
     the autotuned config for this shape bucket (`repro.perf.autotune`,
     cached-only — never triggers a search), otherwise the hand-picked
-    defaults.  Runs at trace time only (static kernel params)."""
+    defaults.  Runs at trace time only (static kernel params).  A broken
+    perf layer raises on a TPU; elsewhere the defaults still work."""
     tuned = None
     if tile_n is None or lane is None:
         try:
             from repro.perf.autotune import tuned_blocks
             tuned = tuned_blocks((x.shape[0], centers.shape[0],
                                   centers.shape[1]))
-        except Exception:   # perf layer absent/broken: defaults still work
+        except Exception:
+            if on_tpu():
+                raise
             tuned = None
         tuned = tuned or {}
     return {"tile_n": tile_n if tile_n is not None
@@ -50,14 +55,15 @@ def fcm_sweep_kernel(x, w, centers, m: float = 2.0, *,
     """Fused Pallas sweep — drop-in for the jnp `engine.fcm_sweep`.
     Block sizes default to the autotuned config for this shape bucket
     when one exists (see `_blocks_for`)."""
-    return fcm_sweep_pallas(x, w, centers, m, interpret=_on_cpu(),
+    return fcm_sweep_pallas(x, w, centers, m, interpret=interpret_mode(),
                             **_blocks_for(x, centers, tile_n, lane))
 
 
 def fcm_accumulate_kernel(x, w, centers, m: float = 2.0, *,
                           tile_n: int = None, lane: int = None):
     """Raw (v_num, w_i, q) accumulators for one record chunk."""
-    return fcm_accumulate_pallas(x, w, centers, m, interpret=_on_cpu(),
+    return fcm_accumulate_pallas(x, w, centers, m,
+                                 interpret=interpret_mode(),
                                  **_blocks_for(x, centers, tile_n, lane))
 
 

@@ -23,7 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.rules import data_axes, get_mesh, get_profile

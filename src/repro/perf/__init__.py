@@ -29,7 +29,7 @@ directory), written atomically (tmp + rename, manifest-style like
 
     {
       "key": {"format_version": 1, "platform": "cpu",
-              "jax": "0.4.37", "backends": ["jnp", "jnp_bf16", ...]},
+              "jax": "0.9.0", "backends": ["jnp", "jnp_bf16", ...]},
       "winners": {"n4096_c8_d16": {"winner": "jnp",
                                    "times_us": {...}, "parity": {...},
                                    "raced_shape": [4096, 8, 16]}},

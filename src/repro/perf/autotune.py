@@ -29,7 +29,8 @@ TILE_GRID = (512, 1024, 2048)
 LANE_GRID_INTERPRET = (32, 128)
 DEFAULT_BLOCKS = {"tile_n": 1024, "lane": 128}
 
-_MEMO: Dict[str, Optional[dict]] = {}   # bucket_key -> tuned cfg | None
+# (platform, bucket_key) -> tuned cfg | None
+_MEMO: Dict[Tuple[str, str], Optional[dict]] = {}
 
 __all__ = ["TILE_GRID", "LANE_GRID_INTERPRET", "DEFAULT_BLOCKS",
            "tune_sweep_blocks", "tuned_blocks"]
@@ -93,7 +94,7 @@ def tune_sweep_blocks(shape: Optional[Tuple[int, int, int]] = None, *,
     data = load_calibration(path)
     data["tiles"][key] = cfg
     store_calibration(data, path)
-    _MEMO[key] = cfg
+    _MEMO[(jax.default_backend(), key)] = cfg
     return cfg
 
 
@@ -104,13 +105,17 @@ def tuned_blocks(shape: Optional[Tuple[int, int, int]] = None, *,
     bucket has never been tuned — callers keep their defaults.  Never
     launches a search (kernel call sites stay cheap and side-effect
     free)."""
+    import jax
+
     from .calibrate import bucket_key, load_calibration, shape_bucket, \
         DEFAULT_SHAPE
 
     bucket = shape_bucket(*(shape if shape is not None else DEFAULT_SHAPE))
-    key = bucket_key(bucket)
+    # The file is keyed by platform (`calibrate._registry_key`); the memo
+    # is too, so blocks tuned on one platform never reach another.
+    key = (jax.default_backend(), bucket_key(bucket))
     if key in _MEMO:
         return _MEMO[key]
-    cfg = load_calibration(path)["tiles"].get(key)
+    cfg = load_calibration(path)["tiles"].get(key[1])
     _MEMO[key] = cfg
     return cfg

@@ -10,7 +10,8 @@ wipe/refresh story in the `repro.perf` package docstring), and answers
 from the in-process memo → disk cache → fresh race, in that order.
 `engine.backend.default_backend_name()` (the platform rule) survives
 only as the fallback when calibration is disabled
-(``REPRO_AUTO_CALIBRATE=0``) or the perf layer itself fails.
+(``REPRO_AUTO_CALIBRATE=0``) or, off the TPU, the perf layer itself
+fails.  On a TPU a failing perf layer or backend raises.
 
 The race also **gates on parity**: each candidate's sweep output is
 checked against the jnp oracle on the race data, and a backend whose
@@ -158,9 +159,13 @@ def race_backends(shape: Tuple[int, int, int], *, m: float = 2.0,
     return (winner_name, per-backend results).
 
     A backend is eligible only if its (centers, objective) agree with
-    the jnp oracle within ``parity_rtol`` on the race data; errors and
-    parity failures are recorded, not raised.  ``jnp`` is always
-    registered and always parity-true, so a winner always exists.
+    the jnp oracle within ``parity_rtol`` on the race data; parity
+    failures are recorded, not raised.  So are errors off the TPU, where
+    the kernels run in interpret mode for parity only; on a TPU a
+    backend that fails to compile or run raises its error, since the
+    race would otherwise hand the fit to the jnp reference in silence.
+    ``jnp`` is always registered and always parity-true, so a winner
+    always exists.
 
     Near-ties go to the oracle: a challenger must beat jnp's time by
     more than ``dethrone_margin`` (5%) to win — race jitter on a loaded
@@ -197,6 +202,8 @@ def race_backends(shape: Tuple[int, int, int], *, m: float = 2.0,
             results[name] = {"us": t * 1e6, "parity_ok": ok,
                              "center_rel_err": dv, "objective_rel_err": dq}
         except Exception as e:
+            if eb.on_tpu():
+                raise
             results[name] = {"error": repr(e), "parity_ok": False}
     eligible = {k: r for k, r in results.items() if r.get("parity_ok")}
     winner = min(eligible, key=lambda k: eligible[k]["us"])

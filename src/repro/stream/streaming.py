@@ -59,11 +59,11 @@ from typing import Iterable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.core.bigfcm import BigFCMConfig, run_driver
 from repro.core.fcm import fcm
 from repro.core.metrics import fuzzy_objective
@@ -259,8 +259,12 @@ class StreamingBigFCM:
                              "zero-mass (all-phantom) batch")
         lam = min(self.cfg.driver_sample, n_real)
         p = w / jnp.maximum(jnp.sum(w), 1e-12)
-        idx = jax.random.choice(k_sample, n, (lam,), replace=False, p=p)
-        v, _flag, _ts, _tf = run_driver(jnp.take(x, idx, axis=0),
+        idx = np.asarray(jax.random.choice(k_sample, n, (lam,),
+                                           replace=False, p=p))
+        # The sample is gathered on the host: on a mesh ``x`` is
+        # row-sharded, and a device gather of sharded rows has no
+        # unambiguous output sharding under `Explicit` mesh axes.
+        v, _flag, _ts, _tf = run_driver(jnp.asarray(np.asarray(x)[idx]),
                                         self._bcfg, k_seed)
         return v
 

@@ -24,7 +24,7 @@ from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.optim import Optimizer, clip_by_global_norm

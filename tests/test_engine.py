@@ -56,11 +56,11 @@ def test_registry_names_and_auto_rule():
         get_backend("cuda")
 
 
-def test_broken_kernels_import_warns_and_degrades_to_jnp():
-    """PR-6 satellite: a poisoned `repro.kernels.ops` import must emit
-    one RuntimeWarning carrying the original error — never a silent
-    degrade to the 50×-slower reference path — and the jnp backends must
-    keep resolving."""
+@pytest.fixture
+def poisoned_kernels():
+    """`repro.kernels.ops` whose import raises, with the registry and
+    module cache restored afterwards."""
+    import importlib.util
     import sys
 
     from repro.engine import backend as backend_mod
@@ -71,8 +71,6 @@ def test_broken_kernels_import_warns_and_degrades_to_jnp():
     saved_backends = {k: backend_mod._REGISTRY.pop(k) for k in
                       ("pallas", "pallas_accumulate")
                       if k in backend_mod._REGISTRY}
-
-    import importlib.util
 
     class _PoisonLoader:
         def create_module(self, spec):
@@ -92,19 +90,71 @@ def test_broken_kernels_import_warns_and_degrades_to_jnp():
     sys.meta_path.insert(0, finder)
     backend_mod._KERNELS_PROBED = False
     try:
-        with pytest.warns(RuntimeWarning,
-                          match="poisoned kernels import"):
-            backend_mod._probe_kernel_backends()
-        # degraded but alive: the jnp family still resolves
-        assert get_backend("jnp").name == "jnp"
-        assert "pallas" not in backend_mod._REGISTRY
-        with pytest.raises(KeyError):
-            get_backend("pallas")
+        yield backend_mod
     finally:
         sys.meta_path.remove(finder)
         sys.modules.update(saved_mods)
         backend_mod._REGISTRY.update(saved_backends)
         backend_mod._KERNELS_PROBED = saved_probed
+
+
+def test_broken_kernels_import_warns_and_degrades_to_jnp(poisoned_kernels):
+    """PR-6 satellite: a poisoned `repro.kernels.ops` import must emit
+    one RuntimeWarning carrying the original error — never a silent
+    degrade to the 50×-slower reference path — and the jnp backends must
+    keep resolving."""
+    backend_mod = poisoned_kernels
+    with pytest.warns(RuntimeWarning, match="poisoned kernels import"):
+        backend_mod._probe_kernel_backends()
+    # degraded but alive: the jnp family still resolves
+    assert get_backend("jnp").name == "jnp"
+    assert "pallas" not in backend_mod._REGISTRY
+    with pytest.raises(KeyError):
+        get_backend("pallas")
+
+
+def test_broken_kernels_import_raises_on_tpu(poisoned_kernels, monkeypatch):
+    """On a TPU the Pallas sweep is the product: a poisoned kernels
+    import raises the original error from every entry that probes, and
+    nothing warns and degrades to jnp."""
+    import warnings
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        for probe in (poisoned_kernels._probe_kernel_backends,
+                      default_backend_name,
+                      lambda: get_backend("jnp")):
+            with pytest.raises(RuntimeError,
+                               match="poisoned kernels import"):
+                probe()
+    assert "pallas" not in poisoned_kernels._REGISTRY
+
+
+@pytest.mark.parametrize("name", ["pairwise_sqdist", "fcm_accumulate",
+                                  "fcm_accumulate_pallas"])
+def test_f32_contractions_ask_for_full_precision(name):
+    """A TPU rounds the inputs of a default-precision f32 matmul to
+    bf16.  Every contraction of the f32 sweeps (jnp and kernel) asks
+    for HIGHEST, so they agree with an f32 reference on the chip too."""
+    from functools import partial
+
+    from repro.engine import backend
+    from repro.kernels.fcm_update import fcm_accumulate_pallas
+
+    x, w, v = _rand(16, 5, 3)
+    fn, args = {
+        "pairwise_sqdist": (backend.pairwise_sqdist, (x, v)),
+        "fcm_accumulate": (partial(backend.fcm_accumulate, m=2.0),
+                           (x, w, v)),
+        "fcm_accumulate_pallas": (partial(fcm_accumulate_pallas, m=2.0,
+                                          interpret=True), (x, w, v)),
+    }[name]
+    text = str(jax.make_jaxpr(fn)(*args))
+    n_dots = text.count("dot_general[")
+    assert n_dots >= 1
+    assert text.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") \
+        == n_dots, text
 
 
 # ----------------------------------------------------- parity (engine) --

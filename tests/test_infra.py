@@ -114,3 +114,56 @@ def test_straggler_monitor_flags_outlier():
         if i == 7:
             assert flagged
     assert mon.flags == 1
+
+
+_CACHE_PROBE = """
+import json, os, sys, uuid
+sys.path.insert(0, {src!r})
+import jax, jax.numpy as jnp
+from repro.launch.cache import REPO_ROOT, enable_compile_cache
+
+def count(d):
+    return sum(len(f) for _, _, f in os.walk(d)) if os.path.isdir(d) else 0
+
+repo_dir = os.path.join(REPO_ROOT, ".cache", "jax")
+env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+before = {{"repo": count(repo_dir), "env": count(env_dir or "")}}
+path = enable_compile_cache()
+salt = uuid.uuid4().int % 1000003          # a program no run compiled yet
+jax.block_until_ready(jax.jit(lambda a: a * 2.0 + salt)(jnp.ones(8)))
+print(json.dumps({{"path": path,
+                  "config": jax.config.jax_compilation_cache_dir,
+                  "repo": count(repo_dir) - before["repo"],
+                  "env": count(env_dir or "") - before["env"]}}))
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_goes_where_the_environment_says(tmp_path, env_set):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, compiled entries land only
+    there; without it, only under ``<repo>/.cache/jax``."""
+    import json
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(
+            src=os.path.abspath(src))],
+        capture_output=True, text=True, timeout=300, env=env,
+        cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    repo_dir = os.path.join(os.path.dirname(os.path.abspath(src)),
+                            ".cache", "jax")
+    want = str(tmp_path) if env_set else repo_dir
+    assert os.path.abspath(rec["path"]) == want
+    assert os.path.abspath(rec["config"]) == want
+    if env_set:
+        assert rec["env"] > 0 and rec["repo"] == 0, rec
+    else:
+        assert rec["repo"] > 0, rec

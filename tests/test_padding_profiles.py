@@ -91,10 +91,10 @@ def test_vocab_padding_masked():
 
 def test_fsdp_profile_specs():
     from jax.sharding import PartitionSpec as P
-    from repro.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.sharding.rules import (logical_to_spec, mesh_context,
                                       profile_context)
-    mesh = abstract_mesh((2, 8), ("data", "model"))
+    mesh = AbstractMesh((2, 8), ("data", "model"))
     with mesh_context(mesh), profile_context("fsdp"):
         # duplicate-axis dedupe: experts take model before embed can
         spec = logical_to_spec(("experts", "embed", None),

@@ -187,6 +187,66 @@ def test_wipe_forces_rerace(calib_dir, monkeypatch):
     assert len(calls) == 2
 
 
+# ------------------------------------------------- fail loud on a TPU --
+
+class _BrokenBackend(backend_mod.SweepBackend):
+    """A backend whose sweep raises, like a kernel Mosaic refuses."""
+    name = "a_broken"
+
+    def accumulate(self, x, w, centers, m):
+        raise RuntimeError("kernel failed to compile (test)")
+
+
+def test_race_records_a_failing_backend_off_tpu(calib_dir, monkeypatch):
+    monkeypatch.setitem(backend_mod._REGISTRY, "a_broken",
+                        _BrokenBackend())
+    winner, results = calibrate.race_backends((256, 4, 4))
+    assert winner != "a_broken"
+    assert "kernel failed to compile" in results["a_broken"]["error"]
+
+
+def test_race_raises_a_failing_backend_on_tpu(calib_dir, monkeypatch):
+    monkeypatch.setitem(backend_mod._REGISTRY, "a_broken",
+                        _BrokenBackend())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="kernel failed to compile"):
+        calibrate.race_backends((256, 4, 4))
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_broken_perf_layer_raises_only_on_tpu(calib_dir, monkeypatch,
+                                              platform):
+    """The catches around the calibration race and the block autotuner
+    fall back off the TPU and raise on it."""
+    from repro.kernels import ops
+
+    def boom(*a, **k):
+        raise RuntimeError("perf layer broken (test)")
+
+    monkeypatch.setattr(calibrate, "calibrated_backend_name", boom)
+    monkeypatch.setattr(autotune, "tuned_blocks", boom)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    x, v = jnp.zeros((64, 4)), jnp.zeros((3, 4))
+    if platform == "tpu":
+        with pytest.raises(RuntimeError, match="perf layer broken"):
+            resolve_backend("auto", shape=SHAPE)
+        with pytest.raises(RuntimeError, match="perf layer broken"):
+            ops._blocks_for(x, v, None, None)
+    else:
+        with pytest.warns(RuntimeWarning, match="perf layer broken"):
+            assert resolve_backend("auto", shape=SHAPE).name == "jnp"
+        assert ops._blocks_for(x, v, None, None) == autotune.DEFAULT_BLOCKS
+
+
+def test_tuned_blocks_are_keyed_by_platform(calib_dir, monkeypatch):
+    """Blocks tuned on one platform never reach another, from the file
+    or the in-process memo."""
+    autotune.tune_sweep_blocks(SHAPE, tiles=(512,), lanes=(32,), iters=1)
+    assert autotune.tuned_blocks(SHAPE)["lane"] == 32
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert autotune.tuned_blocks(SHAPE) is None
+
+
 # ----------------------------------------------------- jnp_bf16 parity --
 
 def test_bf16_accumulators_match_f32_sweep():

@@ -91,3 +91,39 @@ def test_mr_fkm_baseline_equivalent_quality():
     acc = clustering_accuracy(y, assign(x, res.centers), 3)
     assert acc > 0.97
     assert n_jobs > 1 and elapsed > 0
+
+
+_MESH_PAD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys, json
+    sys.path.insert(0, {src!r})
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core import BigFCMConfig, bigfcm_fit
+    from repro.core.metrics import fuzzy_objective, match_centers
+    from repro.data import make_blobs
+    from repro.launch.mesh import make_host_mesh
+
+    x, _ = make_blobs(40003, 8, 4, seed=0)   # 4 devices do not divide it
+    cfg = BigFCMConfig(n_clusters=4, sample_size=512)
+    mesh = make_host_mesh()
+    res = bigfcm_fit(jnp.asarray(x), cfg, mesh=mesh)
+    one = bigfcm_fit(jnp.asarray(x), cfg)
+    q_one = float(fuzzy_objective(jnp.asarray(x), one.centers))
+    print(json.dumps({{
+        "devices": len(mesh.devices.flatten()),
+        "center_dist": match_centers(np.asarray(res.centers),
+                                     np.asarray(one.centers)),
+        "q_mesh": float(res.objective), "q_one": q_one}}))
+""")
+
+
+def test_bigfcm_mesh_pads_rows_the_mesh_does_not_divide():
+    code = _MESH_PAD.format(src=os.path.abspath(SRC))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["devices"] == 4, rec
+    assert rec["center_dist"] < 1e-2, rec
+    assert abs(rec["q_mesh"] - rec["q_one"]) <= 1e-4 * rec["q_one"], rec
