@@ -9,15 +9,17 @@ other subsystem (engine, data, stream, ft, serve, perf):
   * **trace** — nestable, thread-safe ``span("stream.ingest")`` timing
     plus point `event`s, recorded in an in-memory ring buffer and an
     optional atomic JSONL sink; every span feeds a ``span.<name>``
-    latency histogram for free;
+    latency histogram for free and, once jax is imported, is also a
+    ``jax.profiler`` event on the trace's host plane;
   * **report** — `snapshot()` and the per-phase breakdown/renderer
     (``python -m repro.obs.report``).
 
 Environment knobs
 -----------------
 ``REPRO_OBS=0``        kill switch: every instrumentation call becomes
-                       a flag-check no-op (`set_enabled` flips it at
-                       runtime; ``None`` re-reads the env).
+                       a flag-check no-op, profiler events included
+                       (`set_enabled` flips it at runtime; ``None``
+                       re-reads the env).
 ``REPRO_OBS_DIR``      when set, `flush_jsonl()` (and an atexit hook)
                        writes the ring buffer + a final metrics
                        snapshot to ``<dir>/events.jsonl`` atomically.
@@ -65,8 +67,8 @@ it, e.g. ``span.serve.assign`` p99) and additionally a labeled
 This package is pure stdlib — no jax/numpy — so every layer may import
 it unconditionally without cycles or load cost.
 """
-from .metrics import (Counter, Gauge, Histogram, counter, enabled,
-                      gauge, histogram, set_enabled)
+from .metrics import (Counter, Gauge, Handles, Histogram, counter,
+                      enabled, gauge, histogram, set_enabled)
 from .metrics import reset as reset_metrics
 from .metrics import snapshot as metrics_snapshot
 from .trace import (clear, event, flush_jsonl, load_jsonl, ring_events,
@@ -84,7 +86,8 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
+    "Counter", "Gauge", "Handles", "Histogram", "counter", "gauge",
+    "histogram",
     "enabled", "set_enabled", "reset_metrics", "metrics_snapshot",
     "phase_breakdown", "render_report", "snapshot",
     "clear", "event", "flush_jsonl", "load_jsonl", "ring_events",

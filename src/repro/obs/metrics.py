@@ -32,17 +32,20 @@ from __future__ import annotations
 import math
 import os
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 ENV_ENABLE = "REPRO_OBS"
+
+_log10 = math.log10
 
 # histogram defaults: seconds, 8 buckets/decade over [100 ns, ~17 min]
 HIST_LO = 1e-7
 HIST_HI = 1e3
 PER_DECADE = 8
 
-__all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge",
-           "histogram", "enabled", "set_enabled", "snapshot", "reset"]
+__all__ = ["Counter", "Gauge", "Histogram", "Handles", "counter",
+           "gauge", "histogram", "enabled", "set_enabled", "snapshot",
+           "reset"]
 
 
 def _env_enabled() -> bool:
@@ -150,18 +153,17 @@ class Histogram:
         self._min = float("inf")
         self._max = float("-inf")
 
-    def _index(self, v: float) -> int:
-        if v < self.lo:                      # includes v <= 0
-            return 0
-        if v >= self.hi:
-            return len(self._counts) - 1
-        return 1 + int(math.log10(v / self.lo) * self.per_decade)
-
     def observe(self, v: float) -> None:
         if not _ENABLED:
             return
         v = float(v)
-        idx = min(self._index(v), len(self._counts) - 1)
+        last = len(self._counts) - 1
+        if v < self.lo:                      # includes v <= 0
+            idx = 0
+        elif v >= self.hi:
+            idx = last
+        else:                                # rounding may land on hi
+            idx = min(1 + int(_log10(v / self.lo) * self.per_decade), last)
         with self._lock:
             self._counts[idx] += 1
             self._count += 1
@@ -216,6 +218,7 @@ class Histogram:
 
 _LOCK = threading.Lock()
 _METRICS: Dict[Tuple[str, LabelKey], object] = {}
+_GENERATION = 0          # bumped by `reset`
 
 
 def _get(cls, name: str, labels: dict, **kw):
@@ -243,6 +246,44 @@ def gauge(name: str, **labels) -> Gauge:
 
 def histogram(name: str, **labels) -> Histogram:
     return _get(Histogram, name, labels)
+
+
+class Handles:
+    """Metric handles for a hot path, looked up once: ``get()`` returns
+    what ``bind()`` returned, and calls it again only after `reset` has
+    dropped the registry those handles came from."""
+
+    __slots__ = ("_bind", "_gen", "_value")
+
+    def __init__(self, bind: Callable[[], object]):
+        self._bind = bind
+        self._gen = -1
+        self._value = None
+
+    def get(self):
+        if self._gen != _GENERATION:
+            gen = _GENERATION
+            self._value = self._bind()
+            self._gen = gen
+        return self._value
+
+
+_SPAN_HISTS: Dict[object, Tuple[Histogram, ...]] = {}
+
+
+def span_histograms(name: str,
+                    labels: Optional[dict]) -> Tuple[Histogram, ...]:
+    """The histograms a span named ``name`` feeds: the unlabeled
+    ``span.<name>`` aggregate, and ``span.<name>{labels}`` when it has
+    labels.  Resolved once per (name, labels); `reset` forgets them."""
+    key = (name, tuple(labels.items())) if labels else name
+    hs = _SPAN_HISTS.get(key)
+    if hs is None:
+        hs = (histogram("span." + name),)
+        if labels:
+            hs += (histogram("span." + name, **labels),)
+        _SPAN_HISTS[key] = hs
+    return hs
 
 
 def _label_str(labels: LabelKey) -> str:
@@ -276,5 +317,8 @@ def snapshot() -> dict:
 
 def reset() -> None:
     """Drop every registered metric (tests; a fresh run's baseline)."""
+    global _GENERATION
     with _LOCK:
         _METRICS.clear()
+        _SPAN_HISTS.clear()
+        _GENERATION += 1

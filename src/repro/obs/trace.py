@@ -27,12 +27,20 @@ carrying the full metrics snapshot; an atexit hook flushes
 best-effort.  `load_jsonl` reads such a file back, skipping corrupt
 lines.  ``REPRO_OBS=0`` turns `span` into a shared no-op context
 manager and `event` into a flag check.
+
+Once ``jax`` has been imported, every span is also a profiler event:
+it enters ``jax.profiler.TraceAnnotation(name)`` (the bare name, no
+metadata) while a profile is being taken, so the program's spans land
+on the host plane of the trace, on the device trace's clock.  This
+module imports no jax itself; the annotation class is looked up once,
+the first time a span opens after jax is in ``sys.modules``.
 """
 from __future__ import annotations
 
 import atexit
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -71,10 +79,21 @@ def set_ring_size(n: int) -> None:
         _ring = deque(_ring, maxlen=max(int(n), 1))
 
 
+def _as_dict(ev) -> dict:
+    # spans are buffered as tuples (cheaper to record) and take the
+    # event schema when read
+    if type(ev) is dict:
+        return ev
+    name, ts, dur, parent, thread, fields, labels = ev
+    return {**fields, **(labels or {}), "kind": "span", "name": name,
+            "ts": ts, "dur_s": dur, "parent": parent, "thread": thread}
+
+
 def ring_events() -> List[dict]:
     """A copy of the buffered events, oldest first."""
     with _ring_lock:
-        return list(_ring)
+        evs = list(_ring)
+    return [_as_dict(ev) for ev in evs]
 
 
 def clear() -> None:
@@ -99,8 +118,21 @@ def event(name: str, **fields) -> None:
     _append(ev)
 
 
+_ANNOTATION = None       # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation():
+    """The profiler's annotation class, or None while jax is not (yet)
+    imported; resolved once."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = sys.modules.get("jax.profiler")
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
 class _Span:
-    __slots__ = ("name", "fields", "labels", "_t0", "_parent")
+    __slots__ = ("name", "fields", "labels", "_t0", "_parent", "_ann")
 
     def __init__(self, name: str, fields: dict, labels: Optional[dict]):
         self.name = name
@@ -108,9 +140,19 @@ class _Span:
         self.labels = labels
 
     def __enter__(self) -> "_Span":
+        # the profiler event encloses the span's own bookkeeping, so
+        # spans that follow each other leave no gap in the trace
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            ann = ann(self.name)
+            ann.__enter__()
+        else:
+            ann = None
+        self._ann = ann
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
+            _tls.thread = threading.current_thread()
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
         self._t0 = time.perf_counter()
@@ -118,21 +160,22 @@ class _Span:
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
-        _tls.stack.pop()
-        ev = dict(self.fields)
-        if self.labels:
-            ev.update(self.labels)
-        ev.update(kind="span", name=self.name, ts=time.time(),
-                  dur_s=dur, parent=self._parent,
-                  thread=threading.current_thread().name)
-        _append(ev)
-        # the unlabeled histogram is the aggregate series (what SLO
-        # readers key on); labels add a parallel per-label series —
-        # e.g. span.serve.assign{replica=r1} next to span.serve.assign
-        metrics.histogram("span." + self.name).observe(dur)
-        if self.labels:
-            metrics.histogram("span." + self.name,
-                              **self.labels).observe(dur)
+        try:
+            _tls.stack.pop()
+            labels = self.labels
+            ev = (self.name, time.time(), dur, self._parent,
+                  _tls.thread.name, self.fields,
+                  dict(labels) if labels else None)
+            with _ring_lock:
+                _ring.append(ev)
+            # the unlabeled histogram is the aggregate series (what SLO
+            # readers key on); labels add a parallel per-label series —
+            # e.g. span.serve.assign{replica=r1} next to span.serve.assign
+            for h in metrics.span_histograms(self.name, labels):
+                h.observe(dur)
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
         return False
 
 

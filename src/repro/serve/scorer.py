@@ -53,6 +53,14 @@ class _DeviceSnap(NamedTuple):
     centers: jax.Array
 
 
+def to_device(x) -> jax.Array:
+    """A batch on the device as float32: the host-to-device copy, which
+    a float32 device array skips."""
+    if isinstance(x, jax.Array) and x.dtype == jnp.float32:
+        return x
+    return jnp.asarray(x, jnp.float32)
+
+
 class Scorer:
     """A read-only scoring replica over a hot-swappable snapshot.
 
@@ -128,10 +136,11 @@ class Scorer:
 
     def score(self, x, snap: Optional[_DeviceSnap] = None) -> jax.Array:
         """Score ``x`` against ``snap`` (default: the current
-        snapshot).  No padding/instrumentation — the service owns
-        batch shaping; this is the raw device call."""
+        snapshot); returns without waiting for the device.  No
+        padding/instrumentation — the service owns batch shaping and
+        the upload (`to_device`); this is the raw device call."""
         snap = snap if snap is not None else self._snap
-        return self._fn(jnp.asarray(x, jnp.float32), snap.centers)
+        return self._fn(to_device(x), snap.centers)
 
     def assign(self, x):
         """Convenience single-shot scoring: ``(assignments, version)``

@@ -38,12 +38,25 @@ load:
     reads its replica's snapshot exactly once, so every response is
     scored against exactly one version.
 
-Observability: ``serve.queue_depth``/``serve.queue_rows`` gauges,
-``serve.shed``/``serve.deadline_expired``/``serve.served`` counters,
-per-replica ``serve.records``/``serve.batches`` counters and
-``span.serve.assign{replica=...}`` latency series next to the
-unlabeled aggregate (the SLO histogram), plus a ``serve.request``
-end-to-end (submit → response) latency histogram.
+Observability: the spans of the scoring path, each also a profiler
+event (`repro.obs.trace`), nested as they run::
+
+    serve.admit              submit, policy "queue": waiting for room
+    serve.take               worker: waiting for requests, taking them
+    serve.pack               worker: concatenation, padding to the bucket
+    serve.assign{replica=}   worker, per device batch (the SLO series)
+        serve.upload         host-to-device copy of the padded batch
+        serve.launch         the jitted call, result not awaited
+        serve.fetch          waiting for the device, copy back
+    serve.resolve            worker: responses, futures and the
+                             clients' done-callbacks
+
+``serve.take``, ``serve.pack``, ``serve.assign`` and ``serve.resolve``
+tile the worker loop.  Metrics: the ``serve.queue_rows`` gauge;
+``serve.shed``/``serve.shed_rows``/``serve.deadline_expired``
+counters; per-replica ``serve.records``/``serve.batches``/
+``serve.served`` counters; the ``serve.request`` end-to-end (submit →
+response) latency histogram.
 """
 from __future__ import annotations
 
@@ -59,7 +72,7 @@ import numpy as np
 from repro import obs
 from repro.data.plane import bucket_for, pad_rows, shape_buckets
 
-from .scorer import CenterSnapshot, Scorer
+from .scorer import CenterSnapshot, Scorer, to_device
 
 
 class Rejected(RuntimeError):
@@ -144,6 +157,13 @@ class _Request(NamedTuple):
     group: Optional[str] = None   # fairness group (tenant id)
 
 
+class _Metrics(NamedTuple):
+    """The hot path's metric handles, bound once (`obs.Handles`)."""
+    queue_rows: obs.Gauge
+    request: obs.Histogram
+    replicas: dict      # replica id -> (records, batches, served)
+
+
 class ScoringService:
     """The coalescing front-end over N hot-swappable `Scorer` replicas.
 
@@ -175,6 +195,7 @@ class ScoringService:
         self._queued_rows = 0
         self._closed = False
         self._failure: Optional[BaseException] = None
+        self._obs = obs.Handles(self._bind_metrics)
         self._threads = [
             threading.Thread(target=self._worker, args=(s,),
                              name=f"serve-{s.replica}", daemon=True)
@@ -215,18 +236,19 @@ class ScoringService:
                         f"shed", queued_rows=self._queued_rows,
                         limit_rows=self.cfg.queue_rows)
                 deadline = time.monotonic() + self.cfg.deadline_s
-                while not self._admissible(n):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        obs.counter("serve.deadline_expired").add(1)
-                        raise DeadlineExceeded(
-                            f"no queue room for {n} rows within "
-                            f"{self.cfg.deadline_s}s")
-                    self._cond.wait(remaining)
-                    self._check_open()
+                with obs.span("serve.admit"):
+                    while not self._admissible(n):
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            obs.counter("serve.deadline_expired").add(1)
+                            raise DeadlineExceeded(
+                                f"no queue room for {n} rows within "
+                                f"{self.cfg.deadline_s}s")
+                        self._cond.wait(remaining)
+                        self._check_open()
             self._queue.append(req)
             self._queued_rows += n
-            self._gauges()
+            self._gauge()
             self._cond.notify_all()
         return req.future
 
@@ -274,7 +296,7 @@ class ScoringService:
                 pending = list(self._queue)
                 self._queue.clear()
                 self._queued_rows = 0
-                self._gauges()
+                self._gauge()
             self._cond.notify_all()
         for r in pending:
             r.future.set_exception(ServiceClosed(
@@ -305,9 +327,16 @@ class ScoringService:
         if self._closed:
             raise ServiceClosed("scoring service is closed")
 
-    def _gauges(self) -> None:
-        obs.gauge("serve.queue_depth").set(len(self._queue))
-        obs.gauge("serve.queue_rows").set(self._queued_rows)
+    def _bind_metrics(self) -> _Metrics:
+        return _Metrics(
+            obs.gauge("serve.queue_rows"), obs.histogram("serve.request"),
+            {s.replica: tuple(obs.counter(name, replica=s.replica)
+                              for name in ("serve.records", "serve.batches",
+                                           "serve.served"))
+             for s in self.scorers})
+
+    def _gauge(self) -> None:
+        self._obs.get().queue_rows.set(self._queued_rows)
 
     def _take(self):
         """Pop requests for one dispatch (coalescing up to
@@ -350,13 +379,14 @@ class ScoringService:
                             skipped.append(r)
                     self._queue.extend(skipped)   # FIFO order preserved
             self._queued_rows -= rows
-            self._gauges()
+            self._gauge()
             self._cond.notify_all()      # room freed: wake submitters
             return reqs
 
     def _worker(self, scorer: Scorer) -> None:
         while True:
-            reqs = self._take()
+            with obs.span("serve.take"):
+                reqs = self._take()
             if reqs is None:
                 return
             try:
@@ -371,40 +401,54 @@ class ScoringService:
         #                                   bucket slice of an oversized
         #                                   request included) scores
         #                                   against this version
-        x = (reqs[0].x if len(reqs) == 1
-             else np.concatenate([r.x for r in reqs]))
-        total = int(x.shape[0])
-        maxb = self.cfg.max_batch_rows
+        with obs.span("serve.pack"):
+            x = (reqs[0].x if len(reqs) == 1
+                 else np.concatenate([r.x for r in reqs]))
+            total = int(x.shape[0])
+            if self.cfg.coalesce:
+                maxb = self.cfg.max_batch_rows
+                pieces = []
+                for start in range(0, total, maxb):
+                    piece = x[start:start + maxb]
+                    n = int(piece.shape[0])
+                    pieces.append(
+                        (n, pad_rows(piece, bucket_for(n, self._buckets))))
+            else:
+                # one-request-one-dispatch ablation: natural shape, no pad
+                pieces = [(total, x)]
         outs = []
-        if self.cfg.coalesce:
-            for start in range(0, total, maxb):
-                piece = x[start:start + maxb]
-                n = int(piece.shape[0])
-                b = bucket_for(n, self._buckets)
-                xp = pad_rows(piece, b)
-                with obs.span("serve.assign",
-                              labels={"replica": scorer.replica},
-                              rows=n, bucket=b, coalesced=len(reqs)):
-                    out = np.asarray(scorer.score(xp, snap))
-                outs.append(out[:n])
-        else:
-            # one-request-one-dispatch ablation: natural shape, no pad
+        for n, xp in pieces:
             with obs.span("serve.assign",
                           labels={"replica": scorer.replica},
-                          rows=total, coalesced=1):
-                outs.append(np.asarray(scorer.score(x, snap)))
-        out = outs[0] if len(outs) == 1 else np.concatenate(outs)
-        obs.counter("serve.records", replica=scorer.replica).add(total)
-        obs.counter("serve.batches", replica=scorer.replica).add(1)
-        off = 0
-        done = time.perf_counter()
-        for r in reqs:
-            res = ScoreResult(out[off:off + r.n], snap.version,
-                              scorer.replica)
-            off += r.n
-            obs.histogram("serve.request").observe(done - r.t_submit)
-            obs.counter("serve.served", replica=scorer.replica).add(1)
-            r.future.set_result(res)
+                          rows=n, bucket=int(xp.shape[0]),
+                          coalesced=len(reqs)):
+                with obs.span("serve.upload"):
+                    xd = to_device(xp)
+                with obs.span("serve.launch"):
+                    res = scorer.score(xd, snap)
+                with obs.span("serve.fetch"):
+                    out = np.asarray(res)
+            outs.append(out[:n])
+        self._resolve(scorer, reqs, outs, [snap.version] * len(reqs))
+
+    def _resolve(self, scorer, reqs, outs, versions) -> None:
+        """Answer ``reqs`` from the scored pieces ``outs``, request i
+        with ``versions[i]``; the clients' done-callbacks run here."""
+        with obs.span("serve.resolve"):
+            m = self._obs.get()
+            records, batches, served = m.replicas[scorer.replica]
+            out = outs[0] if len(outs) == 1 else np.concatenate(outs)
+            records.add(out.shape[0])
+            batches.add(1)
+            off = 0
+            done = time.perf_counter()
+            for r, version in zip(reqs, versions):
+                res = ScoreResult(out[off:off + r.n], version,
+                                  scorer.replica)
+                off += r.n
+                m.request.observe(done - r.t_submit)
+                served.add(1)
+                r.future.set_result(res)
 
     def _fail(self, exc: BaseException, reqs) -> None:
         """The ShardedLoader contract, service-shaped: the error
@@ -419,7 +463,7 @@ class ScoringService:
             pending = list(self._queue)
             self._queue.clear()
             self._queued_rows = 0
-            self._gauges()
+            self._gauge()
             self._cond.notify_all()
         for r in pending:
             if not r.future.done():

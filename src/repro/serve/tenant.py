@@ -29,7 +29,6 @@ counters.
 """
 from __future__ import annotations
 
-import time
 from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
@@ -189,37 +188,31 @@ class TenantScoringService(ScoringService):
         #                               bucket slice scores against this
         #                               fleet version
         rows = [snap.row_of(r.group) for r in reqs]
-        x = (reqs[0].x if len(reqs) == 1
-             else np.concatenate([r.x for r in reqs]))
-        tidx = np.concatenate([np.full((r.n,), row, np.int32)
-                               for r, row in zip(reqs, rows)])
-        total = int(x.shape[0])
+        with obs.span("serve.pack"):
+            x = (reqs[0].x if len(reqs) == 1
+                 else np.concatenate([r.x for r in reqs]))
+            tidx = np.concatenate([np.full((r.n,), row, np.int32)
+                                   for r, row in zip(reqs, rows)])
+            total = int(x.shape[0])
+            maxb = self.cfg.max_batch_rows
+            pieces = []
+            for start in range(0, total, maxb):
+                piece, tpiece = (x[start:start + maxb],
+                                 tidx[start:start + maxb])
+                n = int(piece.shape[0])
+                b = bucket_for(n, self._buckets) if self.cfg.coalesce else n
+                # phantom rows score against row 0 and are sliced off
+                tp = np.zeros((b,), np.int32)
+                tp[:n] = tpiece
+                pieces.append((n, pad_rows(piece, b), tp))
         distinct = len(set(rows))
-        maxb = self.cfg.max_batch_rows
         outs = []
-        for start in range(0, total, maxb):
-            piece, tpiece = x[start:start + maxb], tidx[start:start + maxb]
-            n = int(piece.shape[0])
-            b = bucket_for(n, self._buckets) if self.cfg.coalesce else n
-            xp = pad_rows(piece, b)
-            # phantom rows score against row 0 and are sliced off
-            tp = np.zeros((b,), np.int32)
-            tp[:n] = tpiece
+        for n, xp, tp in pieces:
             with obs.span("tenant.assign",
                           labels={"tenants": str(distinct)},
-                          rows=n, bucket=b, coalesced=len(reqs),
-                          replica=scorer.replica):
+                          rows=n, bucket=int(xp.shape[0]),
+                          coalesced=len(reqs), replica=scorer.replica):
                 out = np.asarray(scorer.score(xp, tp, snap))
             outs.append(out[:n])
-        out = outs[0] if len(outs) == 1 else np.concatenate(outs)
-        obs.counter("serve.records", replica=scorer.replica).add(total)
-        obs.counter("serve.batches", replica=scorer.replica).add(1)
-        off = 0
-        done = time.perf_counter()
-        for r, row in zip(reqs, rows):
-            res = ScoreResult(out[off:off + r.n],
-                              int(snap.versions[row]), scorer.replica)
-            off += r.n
-            obs.histogram("serve.request").observe(done - r.t_submit)
-            obs.counter("serve.served", replica=scorer.replica).add(1)
-            r.future.set_result(res)
+        self._resolve(scorer, reqs, outs,
+                      [int(snap.versions[row]) for row in rows])

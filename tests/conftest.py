@@ -37,3 +37,28 @@ def seeded_cases(gen, n=20):
         del runner.__wrapped__
         return runner
     return deco
+
+
+def host_profile(run, trace_dir):
+    """Run ``run()`` under the JAX profiler (CPU here) and return the
+    host plane's events as ``(line, name, start_ns, dur_ns)``: a line
+    (numbered) is one thread.  The profiler runs, and the trace is read
+    back, as the chip benchmark does it: no Python tracer,
+    `jax.profiler.ProfileData`."""
+    import glob
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(i, e.name, e.start_ns, e.duration_ns)
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for i, line in enumerate(plane.lines) for e in line.events]

@@ -6,6 +6,7 @@ overhead guard for the <5% budget.
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -136,6 +137,82 @@ def test_span_stack_isolated_per_thread():
     ev = [e for e in obs.ring_events() if e["name"] == "threaded"][0]
     assert ev["parent"] is None              # not "main_scope"
     assert seen["done"]
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
+def test_span_is_a_profiler_event(tmp_path, enabled):
+    """Once jax is imported, an enabled span is a host event of the
+    profiler's trace, under its bare name (labels and fields stay out
+    of it), nested as the spans are; REPRO_OBS=0 leaves none."""
+    import jax  # noqa: F401 — the annotation needs jax imported
+    from conftest import host_profile
+
+    def run():
+        with obs.span("x.y"):
+            with obs.span("x.inner", labels={"replica": "r0"}, rows=3):
+                time.sleep(0.001)
+
+    obs.set_enabled(enabled)
+    events = host_profile(run, tmp_path)
+    mine = {name: (line, s, d) for line, name, s, d in events
+            if name.startswith("x.")}
+    if not enabled:
+        assert mine == {}
+        return
+    assert sorted(mine) == ["x.inner", "x.y"]
+    (lo, so, do), (li, si, di) = mine["x.y"], mine["x.inner"]
+    assert lo == li and so <= si and si + di <= so + do
+    assert di >= 1e6                          # the sleep, in ns
+    assert obs.metrics_snapshot()["histograms"]["span.x.y"]["count"] == 1
+
+
+def test_obs_imports_no_jax():
+    """`repro.obs` stays pure stdlib: spans work, and stay off the
+    profiler, in a process that never imports jax."""
+    import subprocess
+    import sys
+
+    code = ("import sys; from repro import obs\n"
+            "with obs.span('a'):\n    pass\n"
+            "assert obs.ring_events()[0]['name'] == 'a'\n"
+            "assert 'jax' not in sys.modules, 'obs imported jax'\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+
+
+def test_span_histograms_resolved_once_and_after_reset():
+    hs = obs_metrics.span_histograms("t.sh", {"replica": "r0"})
+    assert [h.name for h in hs] == ["span.t.sh", "span.t.sh"]
+    assert hs[1].labels == (("replica", "r0"),)
+    assert obs_metrics.span_histograms("t.sh", {"replica": "r0"}) is hs
+    assert obs_metrics.span_histograms("t.sh", {})[0] is hs[0]
+    obs.reset_metrics()               # a fresh registry: resolved again
+    with obs.span("t.sh", labels={"replica": "r0"}):
+        pass
+    snap = obs.metrics_snapshot()["histograms"]
+    assert snap["span.t.sh"]["count"] == 1
+    assert snap["span.t.sh{replica=r0}"]["count"] == 1
+    assert hs[0].count == 0
+
+
+def test_handles_bind_once_and_again_after_reset():
+    calls = []
+
+    def bind():
+        calls.append(1)
+        return obs.counter("t.bound", replica="r0")
+
+    handles = obs.Handles(bind)
+    c = handles.get()
+    c.add(2)
+    assert handles.get() is c and len(calls) == 1
+    obs.reset_metrics()
+    handles.get().add(3)
+    assert len(calls) == 2 and handles.get() is not c
+    assert obs.metrics_snapshot()["counters"]["t.bound{replica=r0}"] == 3
 
 
 def test_ring_buffer_evicts_oldest_first():

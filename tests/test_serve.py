@@ -394,3 +394,58 @@ def test_per_replica_labels_and_aggregate_histogram():
     assert sum(rec) == total
     # e2e request latency histogram resolves per response
     assert snap["histograms"]["serve.request"]["count"] == len(futs)
+
+
+# -------------------------------------------------------- profiler spans --
+
+def test_scoring_path_spans_on_the_profiler_trace(tmp_path):
+    """Under the profiler, every dispatch leaves one each of
+    ``serve.take``, ``serve.pack``, ``serve.assign`` and
+    ``serve.resolve`` on the worker's line, in that order, with
+    ``serve.upload``, ``serve.launch`` and ``serve.fetch`` inside its
+    ``serve.assign``; a submit that waits for queue room leaves a
+    ``serve.admit`` on the caller's line."""
+    from conftest import host_profile
+
+    scorer = GatedScorer(CenterSnapshot(0, _centers()), backend="jnp")
+    scorer.gate.set()
+    reqs = _reqs(12, lo=20, hi=33, seed=12)
+    cfg = ServiceConfig(max_batch_rows=64, bucket_base=64, queue_rows=64)
+    with ScoringService(scorer, cfg) as warm:      # compile first
+        warm.score(reqs[0], timeout=30)
+    scorer.gate.clear()                            # back the queue up
+    opener = threading.Timer(0.3, scorer.gate.set)
+
+    def run():
+        svc = ScoringService(scorer, cfg)          # its worker starts
+        opener.start()                             # inside the trace
+        futs = [svc.submit(r) for r in reqs]
+        for f in futs:
+            f.result(30)
+        svc.close()
+
+    events = host_profile(run, tmp_path)
+    opener.join(10)
+    batches = int(obs.counter("serve.batches", replica="r0").value) - 1
+    stages = ("serve.take", "serve.pack", "serve.assign", "serve.resolve")
+    worker = [(n, s, d) for line, n, s, d in events
+              if n.startswith("serve.") and n != "serve.admit"]
+    lines = {line for line, n, _, _ in events
+             if n.startswith("serve.") and n != "serve.admit"}
+    assert len(lines) == 1                         # one worker thread
+    by = {}
+    for n, s, d in worker:
+        by.setdefault(n, []).append((s, s + d))
+    # the last take is the one that found the service closed
+    assert len(by["serve.take"]) == batches + 1
+    for n in stages[1:] + ("serve.upload", "serve.launch", "serve.fetch"):
+        assert len(by[n]) == batches, n
+    loop = sorted((s, n) for n, s, _ in worker if n in stages)
+    assert [n for _, n in loop] == list(stages) * batches + ["serve.take"]
+    for i, (a0, a1) in enumerate(by["serve.assign"]):
+        inner = [by[n][i] for n in
+                 ("serve.upload", "serve.launch", "serve.fetch")]
+        assert a0 <= inner[0][0] and inner[-1][1] <= a1
+        assert all(e <= s for (_, e), (s, _) in zip(inner, inner[1:]))
+    admits = [(line, n) for line, n, _, _ in events if n == "serve.admit"]
+    assert admits and not {line for line, _ in admits} & lines
