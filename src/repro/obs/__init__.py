@@ -54,8 +54,9 @@ it, e.g. ``span.serve.assign`` p99) and additionally a labeled
     ``fleet.tombstones``.
   * ``tenants=<T>`` — the tenant plane (`repro.tenant` /
     `repro.serve.tenant`, PR 10) labels ``span.tenant.fit`` with the
-    cohort size of a batched fit and ``span.tenant.assign`` with the
-    number of DISTINCT tenants coalesced into one scoring launch;
+    cohort size of a batched fit and ``span.tenant.assign`` (the
+    single-shot `TenantScorer.assign`) with ``tenants=1``; the tenant
+    service's batches run under the ``serve.*`` spans;
     ``tenant.fit.launches`` counts device dispatches (batched fit: 1;
     the looped baseline: T) so launch amortization is readable next to
     wall time.

@@ -138,7 +138,8 @@ class Scorer:
         """Score ``x`` against ``snap`` (default: the current
         snapshot); returns without waiting for the device.  No
         padding/instrumentation — the service owns batch shaping and
-        the upload (`to_device`); this is the raw device call."""
+        the upload (a float32 device ``x`` is used as it is); this is
+        the raw device call."""
         snap = snap if snap is not None else self._snap
         return self._fn(to_device(x), snap.centers)
 

@@ -26,10 +26,13 @@ load:
     (`DeadlineExceeded`).  Either way queue depth is capped and
     admission keeps a progress guarantee: an oversized request is
     admitted whenever the queue is empty.
-  * **Fail-loud.**  A scoring error resolves the batch's futures with
-    the exception, fails every queued request, and closes the service
-    — the regression-tested `ShardedLoader` idiom (propagate through
-    the queue, never hang a waiting client).
+  * **Fail-loud.**  A scoring error resolves the futures of the
+    batches the worker holds with the exception, fails every queued
+    request, and closes the service — the regression-tested
+    `ShardedLoader` idiom (propagate through the queue, never hang a
+    waiting client).  An error launching the next batch still answers
+    the batch in flight; an error fetching the batch in flight fails it
+    and the next batch too.
   * **Replicas.**  One worker thread per `Scorer` replica keeps each
     device context busy while the queue drains; replicas hot-swap
     snapshots mid-traffic (`swap`, or wire
@@ -39,24 +42,33 @@ load:
     scored against exactly one version.
 
 Observability: the spans of the scoring path, each also a profiler
-event (`repro.obs.trace`), nested as they run::
+event (`repro.obs.trace`), nested as they run.  A worker keeps one
+batch in flight: it launches batch k+1 before it waits for batch k's
+answers, so one turn of its loop is::
 
     serve.admit              submit, policy "queue": waiting for room
-    serve.take               worker: waiting for requests, taking them
-    serve.pack               worker: concatenation, padding to the bucket
-    serve.assign{replica=}   worker, per device batch (the SLO series)
-        serve.upload         host-to-device copy of the padded batch
-        serve.launch         the jitted call, result not awaited
-        serve.fetch          waiting for the device, copy back
-    serve.resolve            worker: responses, futures and the
-                             clients' done-callbacks
+    serve.take               worker: taking batch k+1 (waits only when
+                             no batch is in flight)
+    serve.pack               worker: batch k+1's concatenation, padding
+                             to the bucket
+    serve.assign{replica=}   worker, per turn (the SLO series)
+        serve.upload         host-to-device copy of batch k+1
+        serve.launch         the jitted call on batch k+1, not awaited;
+                             its copy back starts when the answers exist
+        serve.fetch          waiting for batch k's answers on the host
+    serve.resolve            worker: batch k's responses, futures and
+                             the clients' done-callbacks
 
-``serve.take``, ``serve.pack``, ``serve.assign`` and ``serve.resolve``
-tile the worker loop.  Metrics: the ``serve.queue_rows`` gauge;
-``serve.shed``/``serve.shed_rows``/``serve.deadline_expired``
-counters; per-replica ``serve.records``/``serve.batches``/
-``serve.served`` counters; the ``serve.request`` end-to-end (submit →
-response) latency histogram.
+A turn with nothing new to take has no ``serve.pack``, and its
+``serve.assign`` holds only the fetch of the batch in flight; the turn
+that launches the first batch has no ``serve.fetch`` and no
+``serve.resolve``.  ``serve.take``, ``serve.pack``, ``serve.assign``
+and ``serve.resolve`` tile the worker loop.  Metrics: the
+``serve.queue_rows`` gauge; ``serve.shed``/``serve.shed_rows``/
+``serve.deadline_expired`` counters; per-replica ``serve.records``/
+``serve.batches``/``serve.served`` counters and ``serve.overlapped``
+(batches launched while another was in flight); the ``serve.request``
+end-to-end (submit → response) latency histogram.
 """
 from __future__ import annotations
 
@@ -67,12 +79,13 @@ from collections import deque
 from concurrent.futures import Future
 from typing import NamedTuple, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from repro import obs
 from repro.data.plane import bucket_for, pad_rows, shape_buckets
 
-from .scorer import CenterSnapshot, Scorer, to_device
+from .scorer import CenterSnapshot, Scorer
 
 
 class Rejected(RuntimeError):
@@ -157,11 +170,22 @@ class _Request(NamedTuple):
     group: Optional[str] = None   # fairness group (tenant id)
 
 
+class _Batch(NamedTuple):
+    """One packed batch: its requests, the snapshot it scores against
+    (read once, when it is packed), its device batches as ``(rows,
+    host arguments of the scorer's call)``, and each request's
+    version."""
+    reqs: list
+    snap: object
+    pieces: list
+    versions: list
+
+
 class _Metrics(NamedTuple):
     """The hot path's metric handles, bound once (`obs.Handles`)."""
     queue_rows: obs.Gauge
     request: obs.Histogram
-    replicas: dict      # replica id -> (records, batches, served)
+    replicas: dict      # replica id -> (records, batches, served, overlapped)
 
 
 class ScoringService:
@@ -332,15 +356,17 @@ class ScoringService:
             obs.gauge("serve.queue_rows"), obs.histogram("serve.request"),
             {s.replica: tuple(obs.counter(name, replica=s.replica)
                               for name in ("serve.records", "serve.batches",
-                                           "serve.served"))
+                                           "serve.served",
+                                           "serve.overlapped"))
              for s in self.scorers})
 
     def _gauge(self) -> None:
         self._obs.get().queue_rows.set(self._queued_rows)
 
-    def _take(self):
+    def _take(self, wait: bool) -> list:
         """Pop requests for one dispatch (coalescing up to
-        ``max_batch_rows``); None = worker should exit.
+        ``max_batch_rows``).  An empty list means nothing to take: the
+        queue is empty and ``wait`` is off, or the worker should exit.
 
         Without a fairness cap this is the strict FIFO head run.  With
         ``max_group_rows`` set, the scan continues past requests that
@@ -349,11 +375,11 @@ class ScoringService:
         keep their queue position, and the head is always admitted, so
         every request still drains in bounded dispatches."""
         with self._cond:
-            while (not self._queue and self._failure is None
+            while (wait and not self._queue and self._failure is None
                    and not self._closed):
                 self._cond.wait()
             if self._failure is not None or not self._queue:
-                return None
+                return []
             cap = self.cfg.max_group_rows
             reqs = [self._queue.popleft()]
             rows = reqs[0].n
@@ -384,65 +410,112 @@ class ScoringService:
             return reqs
 
     def _worker(self, scorer: Scorer) -> None:
+        """One replica's loop, with one batch in flight: take and launch
+        batch k+1, then wait for batch k's answers and deliver them, so
+        the device's round trip for k overlaps the host's work on k+1.
+        With a batch in flight and the queue empty the take does not
+        wait: the worker answers the batch in flight at once, and a lone
+        request waits for no later arrival.
+
+        A failure fails every request the worker holds that is not
+        answered yet (`_fail` skips answered futures): an error packing
+        or launching k+1 lets k be fetched and answered first."""
+        overlapped = self._obs.get().replicas[scorer.replica][3]
+        labels = {"replica": scorer.replica}
+        held = None        # batch k: (batch, device results), not fetched
         while True:
             with obs.span("serve.take"):
-                reqs = self._take()
-            if reqs is None:
+                reqs = self._take(wait=held is None)
+            if not reqs and held is None:
                 return
-            try:
-                self._dispatch(scorer, reqs)
-            except BaseException as e:    # noqa: BLE001 — fail-loud
-                self._fail(e, reqs)
+            nxt = error = None
+            if reqs:
+                try:
+                    with obs.span("serve.pack"):
+                        batch = self._pack(scorer, reqs)
+                except BaseException as e:    # noqa: BLE001 — fail-loud
+                    error = e
+            with obs.span("serve.assign", labels=labels):
+                if reqs and error is None:
+                    try:
+                        nxt = batch, self._launch(scorer, batch)
+                    except BaseException as e:    # noqa: BLE001
+                        error = e
+                    else:
+                        if held is not None:
+                            overlapped.add(1)
+                if held is not None:
+                    try:
+                        with obs.span("serve.fetch"):
+                            outs = [np.asarray(res)[:n]
+                                    for n, res in held[1]]
+                    except BaseException as e:    # noqa: BLE001
+                        error = e
+                        outs = None
+            if held is not None:
+                if outs is not None:
+                    try:
+                        self._resolve(scorer, held[0], outs)
+                    except BaseException as e:    # noqa: BLE001
+                        error = e
+                reqs = held[0].reqs + reqs
+            if error is not None:
+                self._fail(error, reqs)
                 return
+            held = nxt
 
-    def _dispatch(self, scorer: Scorer, reqs) -> None:
-        snap = scorer.read()              # ONE atomic snapshot read —
-        #                                   the whole dispatch (every
-        #                                   bucket slice of an oversized
-        #                                   request included) scores
-        #                                   against this version
-        with obs.span("serve.pack"):
-            x = (reqs[0].x if len(reqs) == 1
-                 else np.concatenate([r.x for r in reqs]))
-            total = int(x.shape[0])
-            if self.cfg.coalesce:
-                maxb = self.cfg.max_batch_rows
-                pieces = []
-                for start in range(0, total, maxb):
-                    piece = x[start:start + maxb]
-                    n = int(piece.shape[0])
-                    pieces.append(
-                        (n, pad_rows(piece, bucket_for(n, self._buckets))))
-            else:
-                # one-request-one-dispatch ablation: natural shape, no pad
-                pieces = [(total, x)]
-        outs = []
-        for n, xp in pieces:
-            with obs.span("serve.assign",
-                          labels={"replica": scorer.replica},
-                          rows=n, bucket=int(xp.shape[0]),
-                          coalesced=len(reqs)):
-                with obs.span("serve.upload"):
-                    xd = to_device(xp)
-                with obs.span("serve.launch"):
-                    res = scorer.score(xd, snap)
-                with obs.span("serve.fetch"):
-                    out = np.asarray(res)
-            outs.append(out[:n])
-        self._resolve(scorer, reqs, outs, [snap.version] * len(reqs))
+    def _pack(self, scorer: Scorer, reqs) -> _Batch:
+        """Pack ``reqs`` into device batches against ONE atomic snapshot
+        read: every bucket slice of an oversized request scores against
+        this version.  Subclasses that score other arguments override
+        this alone."""
+        snap = scorer.read()
+        x = (reqs[0].x if len(reqs) == 1
+             else np.concatenate([r.x for r in reqs]))
+        pieces = [(n, (pad_rows(x[s:s + n], b),))
+                  for s, n, b in self._slices(int(x.shape[0]))]
+        return _Batch(reqs, snap, pieces, [snap.version] * len(reqs))
 
-    def _resolve(self, scorer, reqs, outs, versions) -> None:
-        """Answer ``reqs`` from the scored pieces ``outs``, request i
-        with ``versions[i]``; the clients' done-callbacks run here."""
+    def _slices(self, total: int) -> list:
+        """``(start, rows, bucket)`` of each device batch that ``total``
+        packed rows fill: slices of at most ``max_batch_rows``, each
+        padded to its bucket; without coalescing, the one request at
+        its natural shape (the ablation: no slicing, no pad)."""
+        if not self.cfg.coalesce:
+            return [(0, total, total)]
+        maxb = self.cfg.max_batch_rows
+        out = []
+        for start in range(0, total, maxb):
+            n = min(maxb, total - start)
+            out.append((start, n, bucket_for(n, self._buckets)))
+        return out
+
+    def _launch(self, scorer, batch: _Batch) -> list:
+        """Upload and launch every device batch of ``batch``; returns
+        ``(rows, result)`` pairs whose copy back to the host has started
+        and is not awaited."""
+        results = []
+        for n, args in batch.pieces:
+            with obs.span("serve.upload"):
+                args = jax.device_put(args)
+            with obs.span("serve.launch"):
+                res = scorer.score(*args, batch.snap)
+                res.copy_to_host_async()
+            results.append((n, res))
+        return results
+
+    def _resolve(self, scorer, batch: _Batch, outs) -> None:
+        """Answer ``batch``'s requests from its fetched pieces ``outs``,
+        each with its version; the clients' done-callbacks run here."""
         with obs.span("serve.resolve"):
             m = self._obs.get()
-            records, batches, served = m.replicas[scorer.replica]
+            records, batches, served, _ = m.replicas[scorer.replica]
             out = outs[0] if len(outs) == 1 else np.concatenate(outs)
             records.add(out.shape[0])
             batches.add(1)
             off = 0
             done = time.perf_counter()
-            for r, version in zip(reqs, versions):
+            for r, version in zip(batch.reqs, batch.versions):
                 res = ScoreResult(out[off:off + r.n], version,
                                   scorer.replica)
                 off += r.n

@@ -20,12 +20,13 @@ service over the whole fleet:
   * `TenantScoringService` — `ScoringService` with tenant routing:
     ``submit(tenant, x)`` tags the request with its tenant id (also the
     fairness group — set ``ServiceConfig.max_group_rows`` so a hot
-    tenant cannot starve a quiet one), and the dispatch path pads
-    cross-tenant batches onto the same bucket ladder.
+    tenant cannot starve a quiet one), and its `_pack` pads
+    cross-tenant batches onto the same bucket ladder; the base
+    service's worker loop launches and answers them.
 
-Observability: dispatches run under ``span.tenant.assign`` with a
-``tenants=<distinct-in-batch>`` label next to the base service's
-counters.
+Observability: the service's batches run under the base service's
+``serve.*`` spans and counters; the single-shot `TenantScorer.assign`
+runs under ``span.tenant.assign{tenants=1}``.
 """
 from __future__ import annotations
 
@@ -36,11 +37,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.data.plane import bucket_for, pad_rows
+from repro.data.plane import pad_rows
 from repro.engine.backend import _u_from_d2
 from repro.tenant.core import TenantSet
 
-from .service import ScoreResult, ScoringService, ServiceConfig
+from .service import ScoreResult, ScoringService, ServiceConfig, _Batch
 
 
 class TenantSnapshot(NamedTuple):
@@ -181,38 +182,22 @@ class TenantScoringService(ScoringService):
         for s in self.scorers:
             s.swap(snap)
 
-    # -- dispatch ----------------------------------------------------------
+    # -- packing -----------------------------------------------------------
 
-    def _dispatch(self, scorer, reqs) -> None:
-        snap = scorer.read()          # ONE read: every row of every
-        #                               bucket slice scores against this
-        #                               fleet version
+    def _pack(self, scorer, reqs) -> _Batch:
+        """Pack a cross-tenant batch: rows and their tenant rows against
+        ONE fleet read, so every bucket slice scores against this
+        version; each request answers with its own tenant's version."""
+        snap = scorer.read()
         rows = [snap.row_of(r.group) for r in reqs]
-        with obs.span("serve.pack"):
-            x = (reqs[0].x if len(reqs) == 1
-                 else np.concatenate([r.x for r in reqs]))
-            tidx = np.concatenate([np.full((r.n,), row, np.int32)
-                                   for r, row in zip(reqs, rows)])
-            total = int(x.shape[0])
-            maxb = self.cfg.max_batch_rows
-            pieces = []
-            for start in range(0, total, maxb):
-                piece, tpiece = (x[start:start + maxb],
-                                 tidx[start:start + maxb])
-                n = int(piece.shape[0])
-                b = bucket_for(n, self._buckets) if self.cfg.coalesce else n
-                # phantom rows score against row 0 and are sliced off
-                tp = np.zeros((b,), np.int32)
-                tp[:n] = tpiece
-                pieces.append((n, pad_rows(piece, b), tp))
-        distinct = len(set(rows))
-        outs = []
-        for n, xp, tp in pieces:
-            with obs.span("tenant.assign",
-                          labels={"tenants": str(distinct)},
-                          rows=n, bucket=int(xp.shape[0]),
-                          coalesced=len(reqs), replica=scorer.replica):
-                out = np.asarray(scorer.score(xp, tp, snap))
-            outs.append(out[:n])
-        self._resolve(scorer, reqs, outs,
+        x = (reqs[0].x if len(reqs) == 1
+             else np.concatenate([r.x for r in reqs]))
+        tidx = np.concatenate([np.full((r.n,), row, np.int32)
+                               for r, row in zip(reqs, rows)])
+        pieces = []
+        for s, n, b in self._slices(int(x.shape[0])):
+            tp = np.zeros((b,), np.int32)   # phantom rows score against
+            tp[:n] = tidx[s:s + n]          # row 0 and are sliced off
+            pieces.append((n, (pad_rows(x[s:s + n], b), tp)))
+        return _Batch(reqs, snap, pieces,
                       [int(snap.versions[row]) for row in rows])

@@ -19,9 +19,11 @@ from repro.data import ChunkStore, bucket_for, pad_rows, shape_buckets
 from repro.ft import CheckpointManager
 from repro.serve import (CenterSnapshot, DeadlineExceeded, Rejected,
                          Scorer, ScoringService, ServiceClosed,
-                         ServiceConfig, SnapshotPublisher, assign_store,
+                         ServiceConfig, SnapshotPublisher, TenantScorer,
+                         TenantScoringService, assign_store,
                          make_assigner, snapshot_from_checkpoint)
 from repro.stream import StreamConfig, StreamingBigFCM
+from repro.tenant import tenant_set
 
 RNG = np.random.default_rng(0)
 D = 6
@@ -45,17 +47,28 @@ def _reqs(k, lo=1, hi=200, seed=1):
             for n in rng.integers(lo, hi, size=k)]
 
 
-class GatedScorer(Scorer):
+class _Gated:
     """Blocks every score call on an event — backs up the queue so
-    overload-policy tests are deterministic."""
+    overload-policy tests are deterministic; ``entered`` is set once a
+    call has reached the gate."""
 
     def __init__(self, *a, **k):
         self.gate = threading.Event()
+        self.entered = threading.Event()
         super().__init__(*a, **k)
 
-    def score(self, x, snap=None):
+    def score(self, *a):
+        self.entered.set()
         self.gate.wait(10)
-        return super().score(x, snap)
+        return super().score(*a)
+
+
+class GatedScorer(_Gated, Scorer):
+    pass
+
+
+class GatedTenantScorer(_Gated, TenantScorer):
+    pass
 
 
 class PoisonScorer(Scorer):
@@ -399,12 +412,13 @@ def test_per_replica_labels_and_aggregate_histogram():
 # -------------------------------------------------------- profiler spans --
 
 def test_scoring_path_spans_on_the_profiler_trace(tmp_path):
-    """Under the profiler, every dispatch leaves one each of
-    ``serve.take``, ``serve.pack``, ``serve.assign`` and
-    ``serve.resolve`` on the worker's line, in that order, with
-    ``serve.upload``, ``serve.launch`` and ``serve.fetch`` inside its
-    ``serve.assign``; a submit that waits for queue room leaves a
-    ``serve.admit`` on the caller's line."""
+    """Under the profiler, each turn of the worker's loop leaves on the
+    worker's line a ``serve.take``, a ``serve.pack`` where it took a
+    batch, a ``serve.assign``, and a ``serve.resolve`` where a batch was
+    in flight, in that order.  Inside the ``serve.assign`` come the new
+    batch's ``serve.upload`` and ``serve.launch``, then the fetch of the
+    batch in flight, ``serve.fetch``.  A submit that waits for queue
+    room leaves a ``serve.admit`` on the caller's line."""
     from conftest import host_profile
 
     scorer = GatedScorer(CenterSnapshot(0, _centers()), backend="jnp")
@@ -422,30 +436,215 @@ def test_scoring_path_spans_on_the_profiler_trace(tmp_path):
         futs = [svc.submit(r) for r in reqs]
         for f in futs:
             f.result(30)
-        svc.close()
+        _close_within(svc)
 
     events = host_profile(run, tmp_path)
     opener.join(10)
     batches = int(obs.counter("serve.batches", replica="r0").value) - 1
     stages = ("serve.take", "serve.pack", "serve.assign", "serve.resolve")
-    worker = [(n, s, d) for line, n, s, d in events
-              if n.startswith("serve.") and n != "serve.admit"]
+    worker = sorted((s, -d, n) for line, n, s, d in events
+                    if n.startswith("serve.") and n != "serve.admit")
     lines = {line for line, n, _, _ in events
              if n.startswith("serve.") and n != "serve.admit"}
     assert len(lines) == 1                         # one worker thread
-    by = {}
-    for n, s, d in worker:
-        by.setdefault(n, []).append((s, s + d))
+    turns, inner = [], []
+    for s, d, n in worker:
+        if n == "serve.take":
+            turns.append([n])
+        elif n in stages:
+            turns[-1].append(n)
+            if n == "serve.assign":
+                assign = (s, s - d)
+                inner.append([])
+        else:                      # upload, launch, fetch: in the assign
+            assert turns[-1][-1] == "serve.assign", n
+            assert assign[0] <= s and s - d <= assign[1], n
+            assert not inner[-1] or inner[-1][-1][1] <= s, n
+            inner[-1].append((n, s - d))
     # the last take is the one that found the service closed
-    assert len(by["serve.take"]) == batches + 1
-    for n in stages[1:] + ("serve.upload", "serve.launch", "serve.fetch"):
-        assert len(by[n]) == batches, n
-    loop = sorted((s, n) for n, s, _ in worker if n in stages)
-    assert [n for _, n in loop] == list(stages) * batches + ["serve.take"]
-    for i, (a0, a1) in enumerate(by["serve.assign"]):
-        inner = [by[n][i] for n in
-                 ("serve.upload", "serve.launch", "serve.fetch")]
-        assert a0 <= inner[0][0] and inner[-1][1] <= a1
-        assert all(e <= s for (_, e), (s, _) in zip(inner, inner[1:]))
+    assert turns.pop() == ["serve.take"]
+    held = False
+    for turn, spans in zip(turns, inner):
+        packed = "serve.pack" in turn
+        assert packed or held
+        assert turn == (["serve.take"] + ["serve.pack"] * packed
+                        + ["serve.assign"] + ["serve.resolve"] * held)
+        assert [n for n, _ in spans] == (
+            ["serve.upload", "serve.launch"] * packed
+            + ["serve.fetch"] * held)
+        held = packed
+    assert not held
+    assert sum("serve.pack" in t for t in turns) == batches
+    overlapped = sum("serve.pack" in t and "serve.resolve" in t
+                     for t in turns)
+    assert overlapped >= 1           # the backed-up queue overlapped
+    assert obs.counter("serve.overlapped",
+                       replica="r0").value == overlapped
     admits = [(line, n) for line, n, _, _ in events if n == "serve.admit"]
     assert admits and not {line for line, _ in admits} & lines
+
+
+# ------------------------------------------------- one batch in flight --
+
+def _close_within(svc, seconds=10.0):
+    """``svc.close()``, failing the test where it, or a worker, hangs."""
+    closer = threading.Thread(target=svc.close, daemon=True)
+    closer.start()
+    closer.join(seconds)
+    assert not closer.is_alive(), "close() hung"
+    assert not any(t.is_alive() for t in svc._threads)
+
+
+def _overlap_counts(replica):
+    return (int(obs.counter("serve.batches", replica=replica).value),
+            int(obs.counter("serve.overlapped", replica=replica).value))
+
+
+def test_batches_in_flight_answer_bit_for_bit_in_fifo_order():
+    """With the queue backed up, every batch after the first launches
+    while the one before it is in flight; the answers still equal
+    per-request scoring bit for bit and resolve in FIFO order."""
+    centers = _centers()
+    scorer = GatedScorer(CenterSnapshot(0, centers), backend="jnp")
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=128,
+                                               bucket_base=32))
+    reqs = _reqs(40, lo=1, hi=100, seed=21)
+    order = []
+    futs = [svc.submit(r) for r in reqs]
+    for i, f in enumerate(futs):
+        f.add_done_callback(lambda _f, i=i: order.append(i))
+    scorer.gate.set()
+    results = [f.result(10) for f in futs]
+    _close_within(svc)
+    ref = make_assigner(centers, backend="jnp")
+    for r, res in zip(reqs, results):
+        assert np.array_equal(res.assignments, np.asarray(ref(r)))
+        assert res.version == 0
+    assert order == list(range(len(reqs)))
+    batches, overlapped = _overlap_counts("r0")
+    assert batches > 2 and overlapped == batches - 1
+
+
+def test_lone_request_is_answered_without_a_later_arrival():
+    """A request followed by an idle queue is answered at once: with a
+    batch in flight and nothing queued the worker fetches instead of
+    waiting for more."""
+    svc = ScoringService(Scorer(CenterSnapshot(0, _centers()),
+                                backend="jnp"),
+                         ServiceConfig(max_batch_rows=64, bucket_base=64))
+    x = RNG.normal(size=(10, D)).astype(np.float32)
+    svc.score(x, timeout=30)                       # compile first
+    t0 = time.monotonic()
+    res = svc.submit(x).result(5)                  # nothing follows it
+    assert time.monotonic() - t0 < 2.0
+    assert res.assignments.shape == (10,)
+    _close_within(svc)
+
+
+def test_overlapped_counter_stays_zero_for_sequential_calls():
+    """``serve.overlapped`` counts only batches launched while another
+    was in flight: strictly sequential calls never overlap."""
+    svc = ScoringService(Scorer(CenterSnapshot(0, _centers()),
+                                backend="jnp"),
+                         ServiceConfig(max_batch_rows=64, bucket_base=64))
+    for r in _reqs(8, lo=1, hi=60, seed=22):
+        svc.score(r, timeout=10)
+    _close_within(svc)
+    assert _overlap_counts("r0") == (8, 0)
+
+
+class _Unfetchable:
+    """Answers whose copy back fails."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *a, **k):
+        raise ValueError("fetch failed")
+
+
+class FaultyScorer(GatedScorer):
+    """Gated; its second call fails at launch (``fault="launch"``), or
+    its first call's answers fail to fetch (``fault="fetch"``)."""
+
+    def __init__(self, *a, fault, **k):
+        self.fault, self.calls = fault, 0
+        super().__init__(*a, **k)
+
+    def score(self, x, snap=None):
+        self.calls += 1
+        if self.fault == "launch" and self.calls == 2:
+            raise ValueError("launch failed")
+        res = super().score(x, snap)
+        return (_Unfetchable() if self.fault == "fetch" and self.calls == 1
+                else res)
+
+
+@pytest.mark.parametrize("fault, answered", [("launch", 1), ("fetch", 0)])
+def test_failure_with_a_batch_in_flight_resolves_every_future(fault,
+                                                              answered):
+    """Three one-request batches: batch 1 in flight while batch 2
+    launches, batch 3 queued.  A failed launch of batch 2 still answers
+    batch 1; a failed fetch of batch 1 fails batches 1 and 2.  Either
+    way the rest fails with the same error, nothing hangs, and the
+    service is closed to new requests."""
+    scorer = FaultyScorer(CenterSnapshot(0, _centers()), backend="jnp",
+                          fault=fault)
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=64))
+    x = RNG.normal(size=(64, D)).astype(np.float32)
+    futs = [svc.submit(x)]
+    assert scorer.entered.wait(10)                 # batch 1 launching
+    futs += [svc.submit(x), svc.submit(x)]
+    scorer.gate.set()
+    for f in futs[:answered]:
+        assert f.result(10).assignments.shape == (64,)
+    for f in futs[answered:]:
+        with pytest.raises(ValueError, match=f"{fault} failed"):
+            f.result(10)
+    with pytest.raises(RuntimeError, match="failed"):
+        svc.submit(x)
+    _close_within(svc)
+
+
+def _fleet(seed, version):
+    rng = np.random.default_rng(seed)
+    return tenant_set([f"u{i}" for i in range(4)],
+                      (rng.normal(size=(4, 5, D)) * 4).astype(np.float32),
+                      np.ones((4, 5), np.float32),
+                      versions=version + np.arange(4))
+
+
+def test_tenant_service_overlaps_batches_across_tenants_and_a_swap():
+    """The tenant service runs the same worker loop: mixed-tenant
+    batches launch while another is in flight.  A swap made while the
+    first batch is in flight leaves that batch on the old fleet and
+    reaches every later one; each answer matches its tenant's centers in
+    the fleet of its version, in FIFO order."""
+    old, new = _fleet(1, 0), _fleet(2, 100)
+    scorer = GatedTenantScorer(old, replica="t0")
+    svc = TenantScoringService(scorer, ServiceConfig(max_batch_rows=64,
+                                                     bucket_base=16))
+    rng = np.random.default_rng(23)
+    sent = [("u0", rng.normal(size=(64, D)).astype(np.float32))]
+    futs = [svc.submit(*sent[0])]
+    assert scorer.entered.wait(10)                 # batch 1 read `old`
+    svc.swap(new)
+    for i in range(24):
+        sent.append((f"u{i % 4}", rng.normal(
+            size=(int(rng.integers(1, 40)), D)).astype(np.float32)))
+        futs.append(svc.submit(*sent[-1]))
+    order = []
+    for i, f in enumerate(futs):
+        f.add_done_callback(lambda _f, i=i: order.append(i))
+    scorer.gate.set()
+    results = [f.result(10) for f in futs]
+    _close_within(svc)
+    refs = {0: TenantScorer(old), 100: TenantScorer(new)}
+    for k, ((tenant, x), res) in enumerate(zip(sent, results)):
+        fleet = 0 if k == 0 else 100
+        want, version = refs[fleet].assign(tenant, x)
+        assert res.version == version
+        assert np.array_equal(res.assignments, want)
+    assert order == list(range(len(futs)))
+    batches, overlapped = _overlap_counts("t0")
+    assert batches > 2 and overlapped == batches - 1

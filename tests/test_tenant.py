@@ -342,9 +342,13 @@ def test_store_stats_absent_on_legacy_manifest():
 def test_tenant_fit_and_assign_spans_labeled():
     data = _cohort(5, seed=12)
     ts = fit_tenants(data, CFG)
-    with TenantScoringService(TenantScorer(ts)) as svc:
+    scorer = TenantScorer(ts)
+    with TenantScoringService(scorer) as svc:
         svc.score("t0", data["t0"][:6], timeout=30)
+    scorer.assign("t0", data["t0"][:6])
     hists = obs.metrics_snapshot()["histograms"]
     assert "span.tenant.fit{tenants=5}" in hists
     assert "span.tenant.fit" in hists       # unlabeled aggregate
-    assert any(k.startswith("span.tenant.assign") for k in hists)
+    assert "span.tenant.assign{tenants=1}" in hists
+    # the service's batches run the base service's worker loop
+    assert "span.serve.assign{replica=t0}" in hists
