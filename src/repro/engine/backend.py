@@ -205,9 +205,13 @@ class SweepBackend:
     """One implementation of the accumulation sweep.
 
     Subclasses provide ``accumulate`` (raw sums) and may override
-    ``sweep`` with a fused version; assignment helpers default to the
-    shared jnp math (distance+argmin/ratio is VPU-trivial) but remain
-    overridable so a backend can own the full serve path too.
+    ``sweep`` with a fused version.  The assignment helpers default to
+    the shared jnp math, which writes the whole (N, C) distance matrix
+    to memory and reads it back: cheap at small C, the larger cost at C
+    in the thousands.  A backend may own the serve path there: the
+    ``pallas`` backend answers ``hard_assign`` with a C-tiled kernel by
+    a rule on the center count (`repro.kernels.ops.nearest_by_kernel`),
+    and ``assigns_by_kernel`` says which path a center count takes.
     """
 
     name: str = "?"
@@ -243,6 +247,11 @@ class SweepBackend:
 
     def hard_assign(self, x, centers):
         return hard_assign(x, centers)
+
+    def assigns_by_kernel(self, n_centers: int) -> bool:
+        """Whether ``hard_assign`` at ``n_centers`` runs a kernel of
+        this backend rather than the shared jnp math."""
+        return False
 
     def __repr__(self):
         return f"<SweepBackend {self.name}>"

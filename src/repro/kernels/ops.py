@@ -4,7 +4,10 @@ This module is where the kernel layer plugs into `repro.engine`: importing
 it registers the ``pallas`` (fused sweep) and ``pallas_accumulate`` (raw
 accumulators, normalization deferred across chunks/slots) backends, which
 is how every consumer reaches the kernels — through
-``engine.resolve_backend``, never by importing sweeps ad hoc.
+``engine.resolve_backend``, never by importing sweeps ad hoc.  The
+``pallas`` backend also owns hard assignment: at large C on a TPU it
+runs the C-tiled nearest-center kernel (`nearest_by_kernel`, a rule on
+the shape it sees, not a timed race).
 ``accumulate_chunks`` folds a chunk stream through the raw entry point —
 one normalization at the end, exactly equal to a single sweep over the
 concatenated records.  On CPU the kernel body runs in interpret mode; on
@@ -20,6 +23,15 @@ from repro.engine.backend import (SweepBackend, normalize_accumulators,
 
 from .fcm_update import (_D2_FLOOR, LANE, fcm_accumulate_pallas,
                          fcm_sweep_pallas)
+from .nearest import nearest_center_pallas
+
+# Hard assignment takes the C-tiled kernel from this many centers on,
+# on a TPU only: the smallest C at which the kernel took less device
+# time than the jnp path at both 256 and 4,096 rows, d = 128, in a
+# chip sweep (`scripts/nearest_sweep.py`; table in PERF.md §6).
+# At C = 256 and 256 rows the jnp path was faster; below 256 the sweep
+# did not go.
+NEAREST_MIN_CENTERS = 1024
 
 
 def interpret_mode() -> bool:
@@ -92,6 +104,12 @@ def accumulate_chunks(chunks, weights, centers, m: float = 2.0, *,
     return v_new, w_i, q
 
 
+def nearest_by_kernel(n_centers: int, tpu: bool) -> bool:
+    """The shape rule of hard assignment: the C-tiled kernel on a TPU
+    at ``NEAREST_MIN_CENTERS`` centers or more, else the jnp path."""
+    return tpu and n_centers >= NEAREST_MIN_CENTERS
+
+
 # --------------------------------------------------- engine registration ---
 
 class PallasBackend(SweepBackend):
@@ -104,6 +122,15 @@ class PallasBackend(SweepBackend):
 
     def sweep(self, x, w, centers, m):
         return fcm_sweep_kernel(x, w, centers, m)
+
+    def assigns_by_kernel(self, n_centers: int) -> bool:
+        return nearest_by_kernel(n_centers, on_tpu())
+
+    def hard_assign(self, x, centers):
+        if self.assigns_by_kernel(centers.shape[0]):
+            return nearest_center_pallas(x, centers,
+                                         interpret=interpret_mode())
+        return super().hard_assign(x, centers)
 
 
 class PallasAccumulateBackend(SweepBackend):

@@ -47,10 +47,13 @@ class CenterSnapshot(NamedTuple):
 
 
 class _DeviceSnap(NamedTuple):
-    """The scorer-internal form: version + device-resident centers.
-    Immutable, so one attribute store publishes it atomically."""
+    """The scorer-internal form: version + device-resident centers, and
+    whether hard labels against them come from the backend's tiled
+    nearest-center kernel.  Immutable, so one attribute store publishes
+    it atomically."""
     version: int
     centers: jax.Array
+    tiled: bool = False
 
 
 def to_device(x) -> jax.Array:
@@ -75,15 +78,17 @@ class Scorer:
         self.replica = str(replica)
         self.m = float(m)
         self.soft = bool(soft)
-        be = resolve_backend(backend)
+        self._backend = be = resolve_backend(backend)
         self._traces = 0
 
         def _score(x, v):
             # trace-time side effect: counts XLA (re)compiles — the
             # compile-count regression tests read `scorer.traces`
             self._traces += 1
-            return (be.soft_assign(x, v, self.m) if self.soft
-                    else be.hard_assign(x, v))
+            if self.soft:
+                return be.soft_assign(x, v, self.m)
+            with jax.named_scope("serve.nearest"):
+                return be.hard_assign(x, v)
 
         self._fn = jax.jit(_score)
         self._snap: Optional[_DeviceSnap] = None
@@ -108,7 +113,9 @@ class Scorer:
         if centers.ndim != 2:
             raise ValueError(f"centers must be (C, d), got "
                              f"{centers.shape}")
-        self._snap = _DeviceSnap(int(version), centers)
+        tiled = (not self.soft
+                 and self._backend.assigns_by_kernel(centers.shape[0]))
+        self._snap = _DeviceSnap(int(version), centers, tiled)
         return int(version)
 
     @property
@@ -118,6 +125,13 @@ class Scorer:
     @property
     def dim(self) -> int:
         return int(self._snap.centers.shape[1])
+
+    @property
+    def tiled(self) -> bool:
+        """Whether hard labels against the current snapshot come from
+        the backend's C-tiled nearest-center kernel (the shape rule,
+        `repro.kernels.ops.nearest_by_kernel`, read once per swap)."""
+        return self._snap.tiled
 
     @property
     def traces(self) -> int:

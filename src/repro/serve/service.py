@@ -66,9 +66,12 @@ that launches the first batch has no ``serve.fetch`` and no
 and ``serve.resolve`` tile the worker loop.  Metrics: the
 ``serve.queue_rows`` gauge; ``serve.shed``/``serve.shed_rows``/
 ``serve.deadline_expired`` counters; per-replica ``serve.records``/
-``serve.batches``/``serve.served`` counters and ``serve.overlapped``
-(batches launched while another was in flight); the ``serve.request``
-end-to-end (submit → response) latency histogram.
+``serve.batches``/``serve.served`` counters, ``serve.overlapped``
+(batches launched while another was in flight) and ``serve.tiled``
+(batches whose hard labels came from the backend's C-tiled
+nearest-center kernel; the device scope ``serve.nearest`` holds the
+hard-label assignment on either path); the
+``serve.request`` end-to-end (submit → response) latency histogram.
 """
 from __future__ import annotations
 
@@ -185,7 +188,8 @@ class _Metrics(NamedTuple):
     """The hot path's metric handles, bound once (`obs.Handles`)."""
     queue_rows: obs.Gauge
     request: obs.Histogram
-    replicas: dict      # replica id -> (records, batches, served, overlapped)
+    replicas: dict      # replica id -> (records, batches, served,
+    #                     overlapped, tiled)
 
 
 class ScoringService:
@@ -357,7 +361,8 @@ class ScoringService:
             {s.replica: tuple(obs.counter(name, replica=s.replica)
                               for name in ("serve.records", "serve.batches",
                                            "serve.served",
-                                           "serve.overlapped"))
+                                           "serve.overlapped",
+                                           "serve.tiled"))
              for s in self.scorers})
 
     def _gauge(self) -> None:
@@ -509,10 +514,14 @@ class ScoringService:
         each with its version; the clients' done-callbacks run here."""
         with obs.span("serve.resolve"):
             m = self._obs.get()
-            records, batches, served, _ = m.replicas[scorer.replica]
+            records, batches, served, _, tiled = m.replicas[scorer.replica]
             out = outs[0] if len(outs) == 1 else np.concatenate(outs)
             records.add(out.shape[0])
             batches.add(1)
+            # only `Scorer` has a tiled path; other scorers (tenants,
+            # stand-ins) have no such attribute
+            if getattr(scorer, "tiled", False):
+                tiled.add(1)
             off = 0
             done = time.perf_counter()
             for r, version in zip(batch.reqs, batch.versions):

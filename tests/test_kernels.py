@@ -133,3 +133,77 @@ def test_kernel_inside_full_fcm_loop():
     np.testing.assert_allclose(np.asarray(r_ref.centers),
                                np.asarray(r_k.centers), rtol=2e-3,
                                atol=2e-4)
+
+
+# -------------------------------------------- nearest-center assignment ---
+
+from repro.engine import get_backend, hard_assign  # noqa: E402
+from repro.kernels import ops  # noqa: E402
+from repro.kernels.nearest import nearest_center_pallas  # noqa: E402
+from repro.kernels.ref import (nearest_center_ref,  # noqa: E402
+                               nearest_center_right)
+
+
+@pytest.mark.parametrize("n,c,d,tiles", [
+    (777, 1000, 41, {}),                  # ragged N, C off the C tile
+    (777, 1000, 128, {}),
+    (300, 2048, 128, {"tile_n": 128, "tile_c": 256}),   # 3 x 8 grid
+    (1500, 700, 41, {"tile_n": 1024, "tile_c": 512}),   # ragged both
+    (64, 23, 41, {}),                     # the KDD shape
+])
+def test_nearest_kernel_matches_float64_reference(n, c, d, tiles):
+    rng = np.random.default_rng(n + c + d)
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    v = (rng.normal(size=(c, d)) * 0.5).astype(np.float32)
+    got = np.asarray(nearest_center_pallas(x, v, interpret=True, **tiles))
+    assert got.shape == (n,) and got.dtype == np.int32
+    assert nearest_center_right(x, v, got).all()
+    want, _ = nearest_center_ref(x, v)
+    np.testing.assert_array_equal(got, want)
+    # the same answers as the jnp path it replaces at large C
+    np.testing.assert_array_equal(got, np.asarray(hard_assign(x, v)))
+
+
+def test_nearest_kernel_duplicates_and_ties_go_to_lowest_index():
+    """Duplicate centers and exact ties, across sublanes and C tiles:
+    the lowest index wins, as `jnp.argmin` gives."""
+    d, c = 41, 200
+    rng = np.random.default_rng(3)
+    v = rng.normal(size=(c, d)).astype(np.float32) + 5.0
+    v[77] = v[9]                  # duplicates in one tile, other sublane
+    v[150] = v[9]                 # and in a later C tile
+    e = np.zeros(d, np.float32)
+    e[0] = 1.0
+    v[20], v[140] = e, -e         # equidistant from the origin
+    x = np.zeros((50, d), np.float32)
+    x[:10] = v[9]                 # on the duplicated center
+    x[10:20] = v[150]
+    # rows 20..49 sit at the origin: d2 = 1 to centers 20 and 140 exactly
+    got = np.asarray(nearest_center_pallas(x, v, interpret=True,
+                                           tile_n=128, tile_c=64))
+    assert (got[:20] == 9).all()
+    assert (got[20:] == 20).all()
+    np.testing.assert_array_equal(got, np.asarray(hard_assign(x, v)))
+    want, _ = nearest_center_ref(x, v)
+    np.testing.assert_array_equal(got, want)
+    # the reference's rule: an alias of the nearest center is right
+    alias = got.copy()
+    alias[:10] = 150
+    assert nearest_center_right(x, v, alias).all()
+    alias[0] = 140
+    assert not nearest_center_right(x, v, alias)[0]
+
+
+def test_nearest_by_kernel_shape_rule():
+    """The C-tiled kernel at `NEAREST_MIN_CENTERS` centers and above,
+    on a TPU only; the jnp path everywhere else."""
+    k = ops.NEAREST_MIN_CENTERS
+    assert ops.nearest_by_kernel(k, tpu=True)
+    assert ops.nearest_by_kernel(65_536, tpu=True)
+    assert not ops.nearest_by_kernel(k - 1, tpu=True)
+    assert not ops.nearest_by_kernel(23, tpu=True)
+    assert not ops.nearest_by_kernel(65_536, tpu=False)
+    # this process is off the TPU: the backend keeps the jnp path
+    pallas = get_backend("pallas")
+    assert not pallas.assigns_by_kernel(65_536)
+    assert not get_backend("jnp").assigns_by_kernel(65_536)

@@ -648,3 +648,61 @@ def test_tenant_service_overlaps_batches_across_tenants_and_a_swap():
     assert order == list(range(len(futs)))
     batches, overlapped = _overlap_counts("t0")
     assert batches > 2 and overlapped == batches - 1
+
+
+# ------------------------------------------ large C: the tiled kernel ---
+
+def test_service_scores_large_c_through_the_tiled_kernel(monkeypatch):
+    """At C = 2,048 and d = 128 (an IVF coarse quantizer's width) the
+    ``pallas`` backend's hard labels come from the C-tiled
+    nearest-center kernel (interpret mode here): the rule is held at
+    1,024 centers, whatever the constant says, and told it is on a TPU.
+    Every label names the
+    float64 nearest center, and ``serve.tiled`` counts every batch."""
+    from repro.kernels import ops
+    from repro.kernels.ref import nearest_center_ref, nearest_center_right
+
+    monkeypatch.setattr(ops, "NEAREST_MIN_CENTERS", 1024)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    rng = np.random.default_rng(15)
+    c, d = 2048, 128
+    centers = rng.normal(size=(c, d)).astype(np.float32)
+    centers[1000] = centers[10]              # a duplicated center
+    scorer = Scorer(CenterSnapshot(0, centers), backend="pallas")
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=256,
+                                               bucket_base=128))
+    reqs = [(centers[rng.integers(0, c, size=n)]
+             + 0.3 * rng.normal(size=(n, d))).astype(np.float32)
+            for n in (1, 37, 128, 200, 300)]
+    reqs.append(centers[[10, 1000]])
+    futs = [svc.submit(r) for r in reqs]
+    results = [f.result(120) for f in futs]
+    svc.close()
+    for r, res in zip(reqs, results):
+        assert nearest_center_right(r, centers, res.assignments).all()
+        want, _ = nearest_center_ref(r, centers)
+        np.testing.assert_array_equal(res.assignments, want)
+    assert list(results[-1].assignments) == [10, 10]
+    batches = int(obs.counter("serve.batches", replica="r0").value)
+    assert batches >= 1
+    assert int(obs.counter("serve.tiled", replica="r0").value) == batches
+
+
+def test_small_c_keeps_the_jnp_path(monkeypatch):
+    """Below the rule's center count the ``pallas`` backend answers with
+    the jnp path, on a TPU too: no batch is counted as tiled."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    centers = _centers(c=23)
+    scorer = Scorer(CenterSnapshot(0, centers), backend="pallas")
+    assert not scorer.tiled
+    with ScoringService(scorer, ServiceConfig(max_batch_rows=128,
+                                              bucket_base=32)) as svc:
+        reqs = _reqs(5, seed=4)
+        results = [svc.score(r, 30) for r in reqs]
+    ref = make_assigner(centers, backend="jnp")
+    for r, res in zip(reqs, results):
+        assert np.array_equal(res.assignments, np.asarray(ref(r)))
+    assert int(obs.counter("serve.batches", replica="r0").value) == 5
+    assert int(obs.counter("serve.tiled", replica="r0").value) == 0

@@ -26,12 +26,19 @@ from repro.core import BigFCMConfig, fcm
 from repro.core.bigfcm import mesh_job
 from repro.engine import get_backend
 from repro.kernels import ops
+from repro.data.plane import shape_buckets
 from repro.kernels.fcm_update import fcm_accumulate_pallas
+from repro.kernels.nearest import nearest_center_pallas
 from repro.perf import calibrate
+from repro.serve import ServiceConfig
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 KDD = (4_898_431, 41, 23)       # rows, features, clusters (paper Table 3)
 HIGGS = (11_000_000, 28, 2)
+IVF = (65_536, 128)             # coarse centers, SIFT width
+_CFG = ServiceConfig()
+SERVE_BUCKETS = shape_buckets(_CFG.max_batch_rows, base=_CFG.bucket_base,
+                              factor=_CFG.bucket_factor)
 
 
 @pytest.fixture(scope="module")
@@ -96,6 +103,19 @@ def test_kernel_compiles_at_paper_widths(one_chip, c, d):
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits_hbm(compiled)
+
+
+@pytest.mark.parametrize("rows", SERVE_BUCKETS)
+def test_nearest_kernel_compiles_at_ivf_width(one_chip, rows):
+    """The C-tiled nearest-center kernel at 65,536 centers of d = 128,
+    at every bucket of the scoring service's default ladder: Mosaic
+    accepts its tiling and VMEM, and no (rows, C) array reaches HBM."""
+    c, d = IVF
+    compiled = jax.jit(partial(nearest_center_pallas,
+                               interpret=False)).lower(
+        _spec((rows, d), one_chip), _spec((c, d), one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _fits_hbm(compiled) < 2 * c * d * 4 + 8 * rows * d * 4
 
 
 def test_tenant_vmapped_kernel_compiles(one_chip):
