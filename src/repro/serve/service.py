@@ -8,9 +8,15 @@ load:
 
   * **Request coalescing.**  Concurrent, arbitrarily-sized requests
     land on one bounded FIFO queue; worker threads drain it greedily,
-    packing adjacent requests into one device batch (up to
-    ``max_batch_rows``) so the device amortizes dispatch overhead
-    across clients instead of paying it per request.
+    packing adjacent requests into one device batch of
+    ``max_batch_rows`` so the device amortizes dispatch overhead
+    across clients instead of paying it per request.  The request that
+    does not fit the room left is split: its first rows fill the
+    batch, and its other rows open the same worker's next batch, so
+    batches are full whenever that many rows are queued, and each
+    response still comes from one replica and one version (a swap
+    between the parts has the second batch score the whole request
+    again).
   * **Shape-bucketed fixed-shape batches.**  A coalesced batch is
     padded up to the smallest bucket of a geometric ladder
     (`repro.data.plane.shape_buckets` — the same phantom-row padding
@@ -48,9 +54,11 @@ answers, so one turn of its loop is::
 
     serve.admit              submit, policy "queue": waiting for room
     serve.take               worker: taking batch k+1 (waits only when
-                             no batch is in flight)
+                             no batch is in flight and no split
+                             request's rest is carried)
     serve.pack               worker: batch k+1's concatenation, padding
-                             to the bucket
+                             to the bucket (twice where a swap came
+                             between a split request's parts)
     serve.assign{replica=}   worker, per turn (the SLO series)
         serve.upload         host-to-device copy of batch k+1
         serve.launch         the jitted call on batch k+1, not awaited;
@@ -67,7 +75,8 @@ and ``serve.resolve`` tile the worker loop.  Metrics: the
 ``serve.queue_rows`` gauge; ``serve.shed``/``serve.shed_rows``/
 ``serve.deadline_expired`` counters; per-replica ``serve.records``/
 ``serve.batches``/``serve.served`` counters, ``serve.overlapped``
-(batches launched while another was in flight) and ``serve.tiled``
+(batches launched while another was in flight), ``serve.split``
+(requests split across two batches) and ``serve.tiled``
 (batches whose hard labels came from the backend's C-tiled
 nearest-center kernel; the device scope ``serve.nearest`` holds the
 hard-label assignment on either path); the
@@ -140,8 +149,8 @@ class ServiceConfig:
     dispatch — the coalescer takes eligible requests past an ineligible
     run in FIFO order, so a firehose group cannot monopolize every
     batch while a quiet group's lone request ages at position 300.
-    ``None`` (default) keeps exact strict-FIFO-run coalescing; the
-    queue head is always admitted (progress guarantee)."""
+    ``None`` (default) keeps strict FIFO coalescing; the queue head is
+    always admitted (progress guarantee)."""
     max_batch_rows: int = 4096
     bucket_base: int = 64
     bucket_factor: int = 2
@@ -171,6 +180,28 @@ class _Request(NamedTuple):
     future: Future
     t_submit: float
     group: Optional[str] = None   # fairness group (tenant id)
+    # a request split across two batches: its first part leaves its
+    # answers in ``stash``, the part that answers reads them as ``head``
+    stash: Optional[list] = None
+    head: Optional[list] = None
+
+
+class _Carry(NamedTuple):
+    """The other rows of a request split across two batches, held by the
+    worker that split it: ``part`` opens that worker's next batch,
+    ``whole`` is the request, and ``snap`` the snapshot its first part
+    was packed against."""
+    part: _Request
+    whole: _Request
+    snap: object = None
+
+
+def _split(r: _Request, k: int):
+    """``r`` split after its first ``k`` rows: the first part, which
+    answers nobody, and the carry of the rest."""
+    answers = []
+    return (r._replace(x=r.x[:k], n=k, stash=answers),
+            _Carry(r._replace(x=r.x[k:], n=r.n - k, head=answers), r))
 
 
 class _Batch(NamedTuple):
@@ -189,7 +220,7 @@ class _Metrics(NamedTuple):
     queue_rows: obs.Gauge
     request: obs.Histogram
     replicas: dict      # replica id -> (records, batches, served,
-    #                     overlapped, tiled)
+    #                     overlapped, tiled, split)
 
 
 class ScoringService:
@@ -362,57 +393,64 @@ class ScoringService:
                               for name in ("serve.records", "serve.batches",
                                            "serve.served",
                                            "serve.overlapped",
-                                           "serve.tiled"))
+                                           "serve.tiled", "serve.split"))
              for s in self.scorers})
 
     def _gauge(self) -> None:
         self._obs.get().queue_rows.set(self._queued_rows)
 
-    def _take(self, wait: bool) -> list:
-        """Pop requests for one dispatch (coalescing up to
-        ``max_batch_rows``).  An empty list means nothing to take: the
-        queue is empty and ``wait`` is off, or the worker should exit.
+    def _take(self, wait: bool, carry: Optional[_Carry] = None):
+        """Pop requests for one dispatch, filled up to ``max_batch_rows``.
+        Returns the requests and, where the batch split a request, the
+        `_Carry` of its other rows; no requests means nothing to take:
+        the queue is empty and ``wait`` is off, or the worker should
+        exit.  A held ``carry`` is always taken, never waited for.
 
-        Without a fairness cap this is the strict FIFO head run.  With
-        ``max_group_rows`` set, the scan continues past requests that
-        don't fit (batch full, or their group already at its cap),
-        taking later eligible requests in FIFO order — skipped requests
-        keep their queue position, and the head is always admitted, so
-        every request still drains in bounded dispatches."""
+        The batch opens with ``carry``'s part, else with the queue's
+        head, taken whole (an oversized one is sliced by `_slices`),
+        and takes the next requests in FIFO order while they fit.  The
+        first that does not fit the room left stops the batch: its first
+        rows fill the room, and its other rows, carried, open this
+        worker's next batch.  So every batch is full while a batch's
+        worth of rows is queued, and nothing is split with fewer.  With
+        ``max_group_rows`` set, a request whose group is at its cap
+        (counting a split's first part) is skipped, keeping its queue
+        position, and the scan goes on; the head is always admitted, so
+        every request still drains in bounded dispatches.  Without
+        coalescing a batch is one request, never split."""
         with self._cond:
-            while (wait and not self._queue and self._failure is None
-                   and not self._closed):
+            while (wait and carry is None and not self._queue
+                   and self._failure is None and not self._closed):
                 self._cond.wait()
-            if self._failure is not None or not self._queue:
-                return []
+            if carry is not None:
+                head, freed = carry.part, 0
+            elif self._failure is not None or not self._queue:
+                return [], None
+            else:
+                head = self._queue.popleft()
+                freed = head.n
+            reqs, split = [head], None
+            room = self.cfg.max_batch_rows - head.n
             cap = self.cfg.max_group_rows
-            reqs = [self._queue.popleft()]
-            rows = reqs[0].n
-            if self.cfg.coalesce:
-                if cap is None:
-                    while (self._queue and rows + self._queue[0].n
-                           <= self.cfg.max_batch_rows):
-                        r = self._queue.popleft()
-                        reqs.append(r)
-                        rows += r.n
-                else:
-                    group_rows = {reqs[0].group: reqs[0].n}
-                    skipped = []
-                    while self._queue:
-                        r = self._queue.popleft()
-                        g_taken = group_rows.get(r.group, 0)
-                        if (rows + r.n <= self.cfg.max_batch_rows
-                                and g_taken + r.n <= cap):
-                            reqs.append(r)
-                            rows += r.n
-                            group_rows[r.group] = g_taken + r.n
-                        else:
-                            skipped.append(r)
-                    self._queue.extend(skipped)   # FIFO order preserved
-            self._queued_rows -= rows
+            group_rows = {head.group: head.n}
+            skipped = []
+            while self.cfg.coalesce and room > 0 and self._queue:
+                r = self._queue.popleft()
+                taken = group_rows.get(r.group, 0)
+                if cap is not None and taken + min(r.n, room) > cap:
+                    skipped.append(r)
+                    continue
+                freed += r.n
+                if r.n > room:                # r stops the batch
+                    r, split = _split(r, room)
+                reqs.append(r)
+                room -= r.n
+                group_rows[r.group] = taken + r.n
+            self._queue.extendleft(reversed(skipped))  # FIFO order kept
+            self._queued_rows -= freed
             self._gauge()
             self._cond.notify_all()      # room freed: wake submitters
-            return reqs
+            return reqs, split
 
     def _worker(self, scorer: Scorer) -> None:
         """One replica's loop, with one batch in flight: take and launch
@@ -422,15 +460,23 @@ class ScoringService:
         wait: the worker answers the batch in flight at once, and a lone
         request waits for no later arrival.
 
+        A request that batch k+1 split opens the worker's next batch
+        with its other rows; where the snapshot changed in between, that
+        batch scores the whole request again, so it answers with one
+        version.
+
         A failure fails every request the worker holds that is not
-        answered yet (`_fail` skips answered futures): an error packing
-        or launching k+1 lets k be fetched and answered first."""
-        overlapped = self._obs.get().replicas[scorer.replica][3]
+        answered yet (`_fail` skips answered futures; a split request is
+        held while either part is): an error packing or launching k+1
+        lets k be fetched and answered first."""
+        *_, overlapped, _, split_count = self._obs.get().replicas[
+            scorer.replica]
         labels = {"replica": scorer.replica}
         held = None        # batch k: (batch, device results), not fetched
+        carry = None       # the rest of a request batch k+1 split
         while True:
             with obs.span("serve.take"):
-                reqs = self._take(wait=held is None)
+                reqs, split = self._take(held is None, carry)
             if not reqs and held is None:
                 return
             nxt = error = None
@@ -438,8 +484,16 @@ class ScoringService:
                 try:
                     with obs.span("serve.pack"):
                         batch = self._pack(scorer, reqs)
+                        if carry is not None and batch.snap is not carry.snap:
+                            reqs[0] = carry.whole     # swapped since
+                            batch = self._pack(scorer, reqs)
                 except BaseException as e:    # noqa: BLE001 — fail-loud
                     error = e
+                else:
+                    if split is not None:
+                        split_count.add(1)
+                        split = split._replace(snap=batch.snap)
+                carry = split
             with obs.span("serve.assign", labels=labels):
                 if reqs and error is None:
                     try:
@@ -511,10 +565,13 @@ class ScoringService:
 
     def _resolve(self, scorer, batch: _Batch, outs) -> None:
         """Answer ``batch``'s requests from its fetched pieces ``outs``,
-        each with its version; the clients' done-callbacks run here."""
+        each with its version; the clients' done-callbacks run here.  A
+        split's first part answers nobody: its answers wait for the
+        part that answers the request, which opens a later batch."""
         with obs.span("serve.resolve"):
             m = self._obs.get()
-            records, batches, served, _, tiled = m.replicas[scorer.replica]
+            records, batches, served, _, tiled, _ = m.replicas[
+                scorer.replica]
             out = outs[0] if len(outs) == 1 else np.concatenate(outs)
             records.add(out.shape[0])
             batches.add(1)
@@ -525,12 +582,16 @@ class ScoringService:
             off = 0
             done = time.perf_counter()
             for r, version in zip(batch.reqs, batch.versions):
-                res = ScoreResult(out[off:off + r.n], version,
-                                  scorer.replica)
+                a = out[off:off + r.n]
                 off += r.n
+                if r.stash is not None:
+                    r.stash.append(a)
+                    continue
+                if r.head:
+                    a = np.concatenate(r.head + [a])
                 m.request.observe(done - r.t_submit)
                 served.add(1)
-                r.future.set_result(res)
+                r.future.set_result(ScoreResult(a, version, scorer.replica))
 
     def _fail(self, exc: BaseException, reqs) -> None:
         """The ShardedLoader contract, service-shaped: the error

@@ -25,6 +25,8 @@ from repro.serve import (CenterSnapshot, DeadlineExceeded, Rejected,
 from repro.stream import StreamConfig, StreamingBigFCM
 from repro.tenant import tenant_set
 
+from conftest import seeded_cases
+
 RNG = np.random.default_rng(0)
 D = 6
 
@@ -706,3 +708,236 @@ def test_small_c_keeps_the_jnp_path(monkeypatch):
         assert np.array_equal(res.assignments, np.asarray(ref(r)))
     assert int(obs.counter("serve.batches", replica="r0").value) == 5
     assert int(obs.counter("serve.tiled", replica="r0").value) == 0
+
+
+# ---------------------------------------------- full batches: the split --
+
+class _Recording(ScoringService):
+    """Records each packed batch's parts as ``(rows, version)``."""
+
+    def __init__(self, *a, **k):
+        self.packed = []
+        super().__init__(*a, **k)
+
+    def _pack(self, scorer, reqs):
+        batch = super()._pack(scorer, reqs)
+        self.packed.append([(r.n, v) for r, v in zip(reqs, batch.versions)])
+        return batch
+
+
+class StepScorer(GatedScorer):
+    """Gated; runs ``hooks[k]()`` as its k-th call starts, and fails its
+    ``fail_at``-th call at launch."""
+
+    def __init__(self, *a, hooks=None, fail_at=None, **k):
+        self.hooks, self.fail_at, self.calls = hooks or {}, fail_at, 0
+        super().__init__(*a, **k)
+
+    def score(self, x, snap=None):
+        self.calls += 1
+        self.hooks.get(self.calls, lambda: None)()
+        if self.calls == self.fail_at:
+            raise ValueError("launch failed")
+        return super().score(x, snap)
+
+
+def _backed_up(svc, scorer, sizes, seed=31):
+    """Submit a plug of ``max_batch_rows`` rows, wait until the gated
+    worker holds it, then queue requests of ``sizes`` rows behind it;
+    returns the requests (plug first), their futures and the order in
+    which the futures resolve, each with the batch that answered it."""
+    rng = np.random.default_rng(seed)
+    reqs = [rng.normal(size=(int(n), D)).astype(np.float32)
+            for n in [svc.cfg.max_batch_rows, *sizes]]
+    order = []
+    batches = obs.counter("serve.batches", replica=scorer.replica)
+    before = int(batches.value)
+    futs = [svc.submit(reqs[0])]
+    assert scorer.entered.wait(10)
+    futs += [svc.submit(r) for r in reqs[1:]]
+    for i, f in enumerate(futs):
+        f.add_done_callback(
+            lambda _f, i=i: order.append((i, int(batches.value) - before)))
+    return reqs, futs, order
+
+
+def _head_run(sizes, m):
+    """The strict FIFO head run, in pure Python: the batch (from 1) that
+    answers each request of ``sizes``, each batch taking its head whole
+    and then the next requests while they fit ``m`` rows."""
+    out, batch, rows = [], 0, m
+    for n in sizes:
+        if rows + n > m:
+            batch, rows = batch + 1, 0
+        rows += n
+        out.append(batch)
+    return out
+
+
+def _splits(sizes, m):
+    """Requests cut by a batch boundary when every batch holds ``m``
+    rows (every size at most ``m``)."""
+    ends = np.cumsum(sizes)
+    return int(np.sum((ends - np.asarray(sizes)) // m < (ends - 1) // m))
+
+
+def test_split_batches_are_full_and_answer_bit_for_bit_in_fifo_order():
+    """A backed-up queue of requests that do not tile the batch: the
+    request that stops each batch is split, so every batch but the last
+    holds ``max_batch_rows`` rows; every answer equals per-request
+    scoring bit for bit, with one version, in FIFO order."""
+    centers = _centers()
+    scorer = GatedScorer(CenterSnapshot(0, centers), backend="jnp")
+    svc = _Recording(scorer, ServiceConfig(max_batch_rows=128,
+                                           bucket_base=32))
+    reqs, futs, order = _backed_up(svc, scorer,
+                                   np.random.default_rng(32).integers(
+                                       1, 100, size=60))
+    scorer.gate.set()
+    results = [f.result(10) for f in futs]
+    _close_within(svc)
+    ref = make_assigner(centers, backend="jnp")
+    for r, res in zip(reqs, results):
+        assert np.array_equal(res.assignments, np.asarray(ref(r)))
+        assert res.version == 0
+    assert [i for i, _ in order] == list(range(len(reqs)))
+    rows = [sum(n for n, _ in parts) for parts in svc.packed]
+    assert rows[:-1] == [128] * (len(rows) - 1) and 0 < rows[-1] <= 128
+    assert sum(rows) == sum(len(r) for r in reqs)
+
+
+@seeded_cases(lambda rng: (int(rng.integers(32, 200)),
+                           rng.integers(1, 400, size=int(
+                               rng.integers(5, 40)))), n=8)
+def test_no_request_answers_later_than_under_the_head_run(case):
+    """Against a pure-Python model of the strict FIFO head run, over
+    random sizes (some larger than a batch): no request is answered in
+    a later batch, and a batch is short only where its head is a whole
+    request larger than the room, or the queue ran dry."""
+    m, sizes = case
+    scorer = GatedScorer(CenterSnapshot(0, _centers()), backend="jnp")
+    svc = _Recording(scorer, ServiceConfig(max_batch_rows=m,
+                                           bucket_base=32))
+    reqs, futs, order = _backed_up(svc, scorer, sizes)
+    scorer.gate.set()
+    for f in futs:
+        f.result(30)
+    _close_within(svc)
+    head_run = _head_run([len(r) for r in reqs], m)
+    for i, batch in order:
+        assert batch <= head_run[i], (i, batch, head_run[i])
+    rows = [sum(n for n, _ in parts) for parts in svc.packed]
+    for parts, n in zip(svc.packed[:-1], rows):
+        assert n == m or (len(parts) == 1 and n > m), svc.packed
+
+
+def test_swap_between_a_split_requests_parts_scores_it_whole_again():
+    """A swap lands after the batch holding a request's first part was
+    packed: the next batch scores the whole request against the new
+    snapshot, so it answers with one version, and that version's
+    centers; the first part's rows are scored twice."""
+    old, new = _centers(seed=1), _centers(seed=2)
+    svc = None
+    scorer = StepScorer(CenterSnapshot(0, old), backend="jnp",
+                        hooks={2: lambda: svc.swap(1, new)})
+    svc = _Recording(scorer, ServiceConfig(max_batch_rows=128,
+                                           bucket_base=32))
+    reqs, futs, _ = _backed_up(svc, scorer, [100, 60, 20])
+    scorer.gate.set()
+    results = [f.result(10) for f in futs]
+    _close_within(svc)
+    refs = {0: make_assigner(old, backend="jnp"),
+            1: make_assigner(new, backend="jnp")}
+    assert [res.version for res in results] == [0, 0, 1, 1]
+    for r, res in zip(reqs, results):
+        assert np.array_equal(res.assignments,
+                              np.asarray(refs[res.version](r)))
+    # batch 2 held the first 28 rows of the 60-row request; batch 3,
+    # packed with its other 32 against the new snapshot, was packed
+    # again with all 60
+    assert svc.packed[1:] == [[(100, 0), (28, 0)], [(32, 1), (20, 1)],
+                              [(60, 1), (20, 1)]]
+    assert obs.counter("serve.records", replica="r0").value == 128 + 208
+    assert obs.counter("serve.split", replica="r0").value == 1
+
+
+@pytest.mark.parametrize("fail_at, answered", [(2, 1), (3, 2)])
+def test_failure_with_a_split_request_held_resolves_every_future(
+        fail_at, answered):
+    """Batch 2 holds the first part of the third request, batch 3 its
+    rest.  A failed launch of either batch fails that request, and
+    everything after it, with the error; what came before is answered,
+    nothing hangs, and ``close()`` returns."""
+    scorer = StepScorer(CenterSnapshot(0, _centers()), backend="jnp",
+                        fail_at=fail_at)
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=64))
+    _, futs, _ = _backed_up(svc, scorer, [40, 40, 10])
+    scorer.gate.set()
+    for f in futs[:answered]:
+        assert f.result(10).assignments.shape[0] > 0
+    for f in futs[answered:]:
+        with pytest.raises(ValueError, match="launch failed"):
+            f.result(10)
+    _close_within(svc)
+
+
+def test_close_with_drain_answers_a_carried_rest():
+    """``close(drain=True)`` made while the queue is backed up: the
+    worker splits a request after the service has closed, carries its
+    rest into its next batch, and answers it."""
+    centers = _centers()
+    scorer = GatedScorer(CenterSnapshot(0, centers), backend="jnp")
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=64))
+    reqs, futs, _ = _backed_up(svc, scorer, [40, 40])
+    closer = threading.Thread(target=svc.close, daemon=True)
+    closer.start()
+    while not svc._closed:
+        time.sleep(0.001)
+    scorer.gate.set()
+    closer.join(10)
+    assert not closer.is_alive(), "close() hung"
+    ref = make_assigner(centers, backend="jnp")
+    for r, f in zip(reqs, futs):
+        assert np.array_equal(f.result(0).assignments, np.asarray(ref(r)))
+    assert obs.counter("serve.split", replica="r0").value == 1
+
+
+def test_no_coalescing_splits_nothing():
+    """``coalesce=False`` scores each request alone at its own shape,
+    however its rows fall against ``max_batch_rows``."""
+    centers = _centers()
+    scorer = GatedScorer(CenterSnapshot(0, centers), backend="jnp")
+    svc = _Recording(scorer, ServiceConfig(max_batch_rows=64,
+                                           coalesce=False))
+    sizes = [40, 40, 100, 7]
+    reqs, futs, _ = _backed_up(svc, scorer, sizes)
+    scorer.gate.set()
+    ref = make_assigner(centers, backend="jnp")
+    for r, f in zip(reqs, futs):
+        assert np.array_equal(f.result(10).assignments, np.asarray(ref(r)))
+    _close_within(svc)
+    assert [[n for n, _ in parts] for parts in svc.packed] == [
+        [64], *([n] for n in sizes)]
+    assert obs.counter("serve.split", replica="r0").value == 0
+
+
+@pytest.mark.parametrize("m", [64, 100])
+def test_split_counter_counts_requests_cut_by_a_batch(m):
+    """``serve.split`` counts the requests split across two batches: on
+    a backed-up queue, one for each batch boundary that falls inside a
+    request; a request that ends a batch exactly is not split."""
+    scorer = GatedScorer(CenterSnapshot(0, _centers()), backend="jnp")
+    svc = ScoringService(scorer, ServiceConfig(max_batch_rows=m,
+                                               bucket_base=32))
+    sizes = list(np.random.default_rng(33).integers(1, m, size=40))
+    sizes[:3] = [m - 10, 10, m]           # two ends on a boundary
+    _, futs, _ = _backed_up(svc, scorer, sizes)
+    scorer.gate.set()
+    for f in futs:
+        f.result(10)
+    _close_within(svc)
+    want = _splits(sizes, m)
+    assert want > 0
+    assert obs.counter("serve.split", replica="r0").value == want
+    assert obs.counter("serve.batches", replica="r0").value == (
+        1 + -(-sum(sizes) // m))

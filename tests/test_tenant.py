@@ -226,9 +226,11 @@ class GatedTenantScorer(TenantScorer):
 
     def __init__(self, *a, **k):
         self.gate = threading.Event()
+        self.entered = threading.Event()
         super().__init__(*a, **k)
 
     def score(self, x, tidx, snap=None):
+        self.entered.set()
         self.gate.wait(10)
         return super().score(x, tidx, snap)
 
@@ -269,6 +271,62 @@ def test_group_cap_prevents_starvation():
     assert _fairness_run(16) <= 2
     # control: uncapped FIFO runs drain the whole firehose first
     assert _fairness_run(None) == 10
+
+
+class _RecordingTenantService(TenantScoringService):
+    """Records each packed batch's parts as ``(tenant, rows, version,
+    tenant rows of its records)``."""
+
+    def __init__(self, *a, **k):
+        self.packed = []
+        super().__init__(*a, **k)
+
+    def _pack(self, scorer, reqs):
+        batch = super()._pack(scorer, reqs)
+        tidx = np.concatenate([tp[:n] for n, (_, tp) in batch.pieces])
+        parts, off = [], 0
+        for r, v in zip(reqs, batch.versions):
+            parts.append((r.group, r.n, v, set(tidx[off:off + r.n])))
+            off += r.n
+        self.packed.append(parts)
+        return batch
+
+
+def test_split_across_tenants_keeps_rows_versions_and_the_cap():
+    """Cross-tenant batches on a backed-up queue under a fairness cap:
+    requests are split to fill batches, each part scores against its
+    own tenant's row and reports that tenant's version, every answer
+    equals the tenant's own assignment, and a split's first part counts
+    against its tenant's cap: no tenant passes the cap in any batch."""
+    ts = _random_tenant_set(4, seed=12, d=D)
+    scorer = GatedTenantScorer(ts, replica="t0")
+    cfg = ServiceConfig(max_batch_rows=64, bucket_base=16,
+                        max_group_rows=24)
+    svc = _RecordingTenantService(scorer, cfg)
+    rng = np.random.default_rng(13)
+    sent = [("u0", rng.normal(size=(64, D)))]
+    futs = [svc.submit(*sent[0])]
+    assert scorer.entered.wait(10)         # the worker holds the plug
+    for _ in range(40):
+        sent.append((f"u{rng.integers(0, 4)}",
+                     rng.normal(size=(int(rng.integers(1, 25)), D))))
+        futs.append(svc.submit(*sent[-1]))
+    scorer.gate.set()
+    results = [f.result(30) for f in futs]
+    svc.close()
+    for (tenant, x), res in zip(sent, results):
+        want, version = scorer.assign(tenant, x)
+        np.testing.assert_array_equal(res.assignments, want)
+        assert res.version == version == int(ts.versions[ts.index(tenant)])
+    for parts in svc.packed:
+        per_tenant = {}
+        for tenant, n, version, rows in parts:
+            assert rows == {ts.index(tenant)}
+            assert version == int(ts.versions[ts.index(tenant)])
+            per_tenant[tenant] = per_tenant.get(tenant, 0) + n
+        assert parts is svc.packed[0] or max(per_tenant.values()) <= 24
+    assert obs.counter("serve.split", replica="t0").value > 0
+    assert obs.counter("serve.served", replica="t0").value == len(sent)
 
 
 def test_group_cap_requires_positive():
